@@ -32,6 +32,7 @@ import jax
 from repro.configs.base import get_smoke_config
 from repro.core.decompress import compress_tree, compressed_bytes
 from repro.core.formats import get_spec
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.serve.engine import GenerationEngine
 
@@ -121,6 +122,7 @@ def main():
                     help="bound the submit queue at N requests; later "
                          "submits are shed instantly; implies --paged")
     args = ap.parse_args()
+    use_compile_cache()
     sla_requested = (args.ttft_slo or args.itl_slo or args.deadline
                      or args.max_queue)
     if (args.trace or args.metrics or args.prefix_cache or args.prefill_chunk

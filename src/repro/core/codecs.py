@@ -41,6 +41,7 @@ import numpy as np
 
 # E2M1 magnitude grid (sign handled separately): code 0..7.
 FP4_GRID = np.array([0.0, 0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0], dtype=np.float32)
+_FP4_MIDS = (FP4_GRID[1:] + FP4_GRID[:-1]) / 2.0  # nearest-level cut points
 
 # NormalFloat4 (QLoRA): 16 quantiles of N(0,1) normalized to [-1, 1].
 NF4_LUT = np.array(
@@ -103,15 +104,17 @@ def quantize_bf8_jnp(x: jax.Array) -> jax.Array:
 
 
 def dequantize_bf8_jnp(code: jax.Array) -> jax.Array:
-    bits = code.astype(jnp.uint16) << 8
-    return jax.lax.bitcast_convert_type(bits, jnp.float16).astype(jnp.bfloat16)
+    # every E5M2 value is exact in bf16
+    return _bf8_decode_jnp(code).astype(jnp.bfloat16)
 
 
 def quantize_fp4(x: np.ndarray) -> np.ndarray:
     """f32 (already divided by group scale) -> E2M1 code (uint8 in [0,16))."""
     sign = (x < 0).astype(np.uint8)
-    mag = np.abs(x.astype(np.float32))
-    idx = np.argmin(np.abs(mag[..., None] - FP4_GRID), axis=-1).astype(np.uint8)
+    mag = np.abs(x, dtype=np.float32)
+    # nearest grid point, ties to the lower one; no (..., 8) distance table,
+    # which at a 9216 x 36864 weight would take tens of GB of host memory
+    idx = np.searchsorted(_FP4_MIDS, mag, side="left").astype(np.uint8)
     return (sign << 3) | idx
 
 
@@ -120,10 +123,44 @@ def dequantize_fp4(code: np.ndarray) -> np.ndarray:
     return np.where(code >> 3 == 1, -mag, mag)
 
 
+def _widen(codes: jax.Array) -> jax.Array:
+    """Stored uint8 codes -> int32 in [0, 256). Every jnp decoder widens
+    first: Mosaic has no 8-bit shifts and no uint8 -> float cast, and the
+    int32 forms give the same values everywhere."""
+    return codes.astype(jnp.int32)
+
+
+def _f32_from_bits(bits: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _interleave_lanes(lo: jax.Array, hi: jax.Array) -> jax.Array:
+    """(..., n) int32 nibbles x2 -> (..., 2n), `lo` at even and `hi` at odd
+    positions of the last axis. Mosaic has no lane shuffle, so this is a
+    one-hot contraction on the MXU: exact, since nibbles are exact in bf16
+    and each output sums one of them with zeros."""
+    n = lo.shape[-1]
+    src = jax.lax.broadcasted_iota(jnp.int32, (n, 2 * n), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (n, 2 * n), 1)
+
+    def place(x, offset):
+        onehot = (dst == 2 * src + offset).astype(jnp.bfloat16)
+        return jnp.dot(
+            x.reshape(-1, n).astype(jnp.float32).astype(jnp.bfloat16), onehot,
+            preferred_element_type=jnp.float32,
+        )
+
+    out = place(lo, 0) + place(hi, 1)
+    return out.astype(jnp.int32).reshape(*lo.shape[:-1], 2 * n)
+
+
 def _unpack_nibbles_jnp(codes: jax.Array, axis: int) -> jax.Array:
-    """Packed uint8 -> nibbles along `axis` (even index = low nibble)."""
+    """Packed uint8 -> int32 nibbles along `axis` (even index = low nibble)."""
     axis = axis % codes.ndim
-    lo, hi = codes & 0xF, codes >> 4
+    c = _widen(codes)
+    lo, hi = c & 0xF, c >> 4
+    if axis == codes.ndim - 1:
+        return _interleave_lanes(lo, hi)
     stacked = jnp.stack([lo, hi], axis=axis + 1)
     shape = list(codes.shape)
     shape[axis] *= 2
@@ -223,12 +260,14 @@ class Codec:
         raise NotImplementedError
 
     def decode_scales(self, scales: jax.Array) -> jax.Array:
-        """(ng, N) stored scales -> (ng, N) f32 multipliers."""
+        """(ng, N) stored scales -> (ng, N) f32 multipliers, built from
+        bits (exact; no `exp2`, no 8- or 16-bit arithmetic)."""
+        s = scales.astype(jnp.int32)
         if self.scale_kind == "e8m0":
-            return jnp.exp2(scales.astype(jnp.float32) - 127.0)
-        return jax.lax.bitcast_convert_type(
-            scales.astype(jnp.uint16), jnp.bfloat16
-        ).astype(jnp.float32)
+            # 2^(e-127) is the f32 with exponent field e; e == 0 is the
+            # subnormal 2^-127 (mantissa bit 22)
+            return _f32_from_bits(jnp.where(s == 0, 1 << 22, s << 23))
+        return _f32_from_bits(s << 16)  # bf16 bits are the f32's top half
 
     # -- KV-cache path -----------------------------------------------------
     def kv_encode(self, x: jax.Array) -> Tuple[jax.Array, Optional[jax.Array]]:
@@ -269,11 +308,10 @@ class BF16Codec(Codec):
         return _bf16_bits_to_f32(lo | (hi << 8))
 
     def decode_values(self, codes):
-        lo = codes[:, 0::2, :].astype(jnp.uint16)
-        hi = codes[:, 1::2, :].astype(jnp.uint16)
-        return jax.lax.bitcast_convert_type(lo | (hi << 8), jnp.bfloat16).astype(
-            jnp.float32
-        )
+        ng, packed, n = codes.shape
+        pairs = _widen(codes).reshape(ng, packed // 2, 2, n)
+        lo, hi = pairs[:, :, 0, :], pairs[:, :, 1, :]
+        return _f32_from_bits((hi << 24) | (lo << 16))
 
 
 class BF8Codec(Codec):
@@ -288,8 +326,7 @@ class BF8Codec(Codec):
         return dequantize_bf8(codes)
 
     def decode_values(self, codes):
-        bits = codes.astype(jnp.uint16) << 8
-        return jax.lax.bitcast_convert_type(bits, jnp.float16).astype(jnp.float32)
+        return _bf8_decode_jnp(codes)
 
     def kv_encode(self, x):
         return quantize_bf8_jnp(x), None
@@ -298,25 +335,37 @@ class BF8Codec(Codec):
         return dequantize_bf8_jnp(codes)
 
 
+def _bf8_decode_jnp(codes: jax.Array) -> jax.Array:
+    """E5M2 code -> f32, built from bits: the f16 -> f32 widening of the
+    code's high-byte f16 without 16-bit arithmetic (Mosaic has none)."""
+    c = _widen(codes)
+    e, m = (c >> 2) & 0x1F, c & 0x3
+    normal = ((e + (127 - 15)) << 23) | (m << 21)
+    # e == 31: inf (m == 0) or a quiet NaN, as the f16 -> f32 cast gives
+    special = jnp.where(m == 0, 0x7F800000, 0x7FC00000 | (m << 21))
+    # e == 0: subnormal m * 2^-16; exact and a normal f32
+    sub = jax.lax.bitcast_convert_type(
+        m.astype(jnp.float32) * (2.0 ** -16), jnp.int32
+    )
+    mag = jnp.where(e == 0, sub, jnp.where(e == 31, special, normal))
+    return _f32_from_bits(mag | ((c >> 7) << 31))
+
+
 def _fp4_mag_jnp(nib: jax.Array) -> jax.Array:
     """E2M1 nibble (sign stripped) -> magnitude, pure ALU (no LUT).
 
     value = m/2              if e == 0   (subnormal)
-          = (1 + m/2)*2^(e-1) otherwise
+          = (1 + m/2)*2^(e-1) otherwise   (f32 exponent field e + 126)
     """
-    e = ((nib >> 1) & 0x3).astype(jnp.float32)
-    m = (nib & 0x1).astype(jnp.float32)
-    normal = (1.0 + 0.5 * m) * jnp.exp2(e - 1.0)
-    return jnp.where(e == 0.0, 0.5 * m, normal)
+    e = (nib >> 1) & 0x3
+    m = nib & 0x1
+    normal = _f32_from_bits(((e + 126) << 23) | (m << 22))
+    return jnp.where(e == 0, 0.5 * m.astype(jnp.float32), normal)
 
 
 def _fp4_decode_jnp(nib: jax.Array) -> jax.Array:
     mag = _fp4_mag_jnp(nib)
     return jnp.where((nib >> 3) == 1, -mag, mag)
-
-
-# midpoints between adjacent FP4_GRID magnitudes: nearest-grid quantizer
-_FP4_MIDS = (FP4_GRID[1:] + FP4_GRID[:-1]) / 2.0
 
 
 class MXFP4Codec(Codec):
@@ -391,11 +440,16 @@ class IntCodec(Codec):
             q = (nib - 16 * (nib >= 8)).astype(np.float32)
         return q * _bf16_bits_to_f32(scales)[:, None, :]
 
-    def decode_values(self, codes):
+    def _values(self, codes: jax.Array, axis: int) -> jax.Array:
+        """Two's-complement codes (nibbles packed along `axis`) -> f32."""
         if self.bits == 8:
-            return codes.astype(jnp.int8).astype(jnp.float32)
-        nib = _unpack_nibbles_jnp(codes, axis=1).astype(jnp.int32)
+            c = _widen(codes)
+            return (c - 256 * (c >= 128)).astype(jnp.float32)
+        nib = _unpack_nibbles_jnp(codes, axis=axis)
         return (nib - 16 * (nib >= 8)).astype(jnp.float32)
+
+    def decode_values(self, codes):
+        return self._values(codes, axis=1)
 
     def kv_encode(self, x):
         scale, safe = self._kv_scale(x, float(self.qmax))
@@ -408,11 +462,7 @@ class IntCodec(Codec):
         return _pack_nibbles_jnp((q & 0xF).astype(jnp.uint8), axis=-1), scale
 
     def kv_decode(self, codes, scales):
-        if self.bits == 8:
-            q = codes.astype(jnp.int8).astype(jnp.float32)
-        else:
-            nib = _unpack_nibbles_jnp(codes, axis=-1).astype(jnp.int32)
-            q = (nib - 16 * (nib >= 8)).astype(jnp.float32)
+        q = self._values(codes, axis=-1)
         return q * scales.astype(jnp.float32)[..., None]
 
 

@@ -28,11 +28,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import codecs
 
-try:  # moved between jax versions
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover — newer jax: top-level function
-    shard_map = jax.shard_map  # type: ignore[attr-defined]
-
 # canonical methods; any name in codecs.kv_codec_names() is accepted
 METHODS = ("int8", "bf8")
 
@@ -105,12 +100,12 @@ def make_compressed_allreduce(
     # replicated in/out: every device carries its full local gradient; the
     # psum inside is the only cross-device traffic
     allreduce = jax.jit(
-        shard_map(
+        jax.shard_map(
             _body,
             mesh=mesh,
             in_specs=(P(), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
     return allreduce, init_err

@@ -141,16 +141,28 @@ def pick_blocks(
         bm, tn = m, max(target_n, 2 * LANES)
     else:
         bm, tn = select_block(m, target_m, multiple=SUBLANES, name="block_m"), target_n
-    bn = select_block(n, tn, multiple=LANES, name="block_n")
+    bn = lane_block(n, tn)
     bk = select_block(k, target_k, multiple=spec.group, name="block_k")
     while _gemm_vmem_bytes(bm, bn, bk, spec) > vmem_budget:
         if bk > spec.group:
             bk = select_block(k, bk // 2, multiple=spec.group, name="block_k")
         elif bn > 1:
-            bn = select_block(n, bn // 2, multiple=LANES, name="block_n")
+            bn = lane_block(n, bn // 2)
         else:  # pragma: no cover - tiny shapes always fit
             break
     return bm, bn, bk
+
+
+def lane_block(n: int, target: int) -> int:
+    """block_n for an output dim `n`: the largest lane-aligned divisor <=
+    `target`. A dim wider than a lane row with no such divisor (a 50272
+    vocab) gets a lane-aligned block and a ragged last block instead — the
+    kernels grid N with `pl.cdiv`, and since every column decodes and
+    contracts on its own, the last block's out-of-range columns touch only
+    output columns that are never stored."""
+    if n > LANES and n % LANES:
+        return max(LANES, target // LANES * LANES)
+    return select_block(n, target, multiple=LANES, name="block_n")
 
 
 # ---------------------------------------------------------------------------

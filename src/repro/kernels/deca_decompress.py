@@ -10,7 +10,8 @@ Maps the DECA PE onto a TPU core:
   Dequantization (LUT array)      ALU decode on the VPU: E5M2/E2M1 -> BF16 via
                                   integer shift/mask/select (no per-lane LUT
                                   SRAM on TPU; see DESIGN.md §2)
-  Expansion (prefix-sum + XBAR)   cumsum over the bitmask + take_along_axis
+  Expansion (prefix-sum + XBAR)   popcount of the masked-below bitmask + a
+                                  select per packed slot
   Scaling (BF16 multipliers)      per-group broadcast multiply
   TOut registers                  VMEM output block
 
@@ -32,6 +33,7 @@ from repro.core.codecs import get_codec
 from repro.core.compression import CompressedTensor
 from repro.core.formats import CompressionSpec
 from repro.kernels.autotune import select_block
+from repro.kernels.mode import resolve_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -67,12 +69,21 @@ def decompress_block(
     ng, _, n = vals.shape
     if mask is None:
         return vals.reshape(ng * spec.group, n)
-    shifts = jnp.arange(spec.group, dtype=jnp.uint32)[None, :, None]
-    bits = ((mask[:, None, :] >> shifts) & 1).astype(jnp.int32)  # (ng, G, n)
-    prefix = jnp.cumsum(bits, axis=1) - bits  # POPCNT/prefix-sum analog
-    idx = jnp.clip(prefix, 0, spec.k_cap - 1)
-    gathered = jnp.take_along_axis(vals, idx, axis=1)  # crossbar analog
-    dense = jnp.where(bits == 1, gathered, 0.0)
+    # POPCNT analog: element i of a group is the slot-th kept value, where
+    # slot = popcount(mask & (2^i - 1)); unkept elements get slot k_cap
+    m = mask.astype(jnp.uint32)[:, None, :]  # (ng, 1, n)
+    i = jax.lax.broadcasted_iota(jnp.uint32, (1, spec.group, 1), 1)
+    kept = ((m >> i) & 1) == 1
+    slot = jnp.where(
+        kept,
+        jax.lax.population_count(m & ((jnp.uint32(1) << i) - 1)),
+        spec.k_cap,
+    ).astype(jnp.int32)  # (ng, G, n)
+    # crossbar analog: a select per packed slot (a per-column gather along
+    # sublanes, which Mosaic cannot lower)
+    dense = jnp.zeros((ng, spec.group, n), vals.dtype)
+    for j in range(spec.k_cap):
+        dense = jnp.where(slot == j, vals[:, j : j + 1, :], dense)
     return dense.reshape(ng * spec.group, n)
 
 
@@ -106,7 +117,7 @@ def decompress_pallas(
     block_k: int = 512,
     block_n: int = 256,
     out_dtype=jnp.bfloat16,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Decompress a CompressedTensor to a dense (K, N) array via Pallas."""
     spec = ct.spec
@@ -144,8 +155,8 @@ def decompress_pallas(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_k, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, N), out_dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)

@@ -38,6 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.compression import CompressedTensor
 from repro.kernels.autotune import pick_blocks, select_block
 from repro.kernels.deca_decompress import decompress_block
+from repro.kernels.mode import resolve_interpret
 
 
 def _unpack_refs(spec, refs):
@@ -109,7 +110,7 @@ def decompress_gemm_pallas(
     block_n: Optional[int] = None,
     block_k: Optional[int] = None,
     out_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """x (M, K) @ decompress(ct) (K, N) -> (M, N), decompression fused.
 
@@ -145,7 +146,7 @@ def decompress_gemm_pallas(
     ck = ct.codes.shape[1]
     nk = K // block_k
 
-    grid = (M // block_m, N // block_n, nk)
+    grid = (M // block_m, pl.cdiv(N, block_n), nk)
     in_specs = [
         pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
         *_compressed_specs(
@@ -161,10 +162,10 @@ def decompress_gemm_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, *_ct_operands(ct))
     return out
 
@@ -180,7 +181,7 @@ def decompress_gemv_pallas(
     block_n: Optional[int] = None,
     block_k: Optional[int] = None,
     out_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Decode-shaped fused GeMV: x (M, K) @ W (K, N) with M kept whole.
 
@@ -216,7 +217,7 @@ def decompress_gemv_pallas(
     ck = ct.codes.shape[1]
     nk = K // block_k
 
-    grid = (N // block_n, nk)
+    grid = (pl.cdiv(N, block_n), nk)
     in_specs = [
         pl.BlockSpec((M, block_k), lambda j, k: (0, k)),
         *_compressed_specs(
@@ -232,9 +233,9 @@ def decompress_gemv_pallas(
         out_specs=pl.BlockSpec((M, block_n), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((M, block_n), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, *_ct_operands(ct))
     return out

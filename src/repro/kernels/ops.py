@@ -20,13 +20,13 @@ dequantize-on-read via the codec registry, and never materialize the
 gathered (B, MB*bsize, Hkv, Dh) KV view.
 
 Compile mode is one switch for all four kernel entry points (decompress,
-gemm, gemv, paged attention): `REPRO_PALLAS_INTERPRET=1` forces interpret
-mode even on TPU (debugging), `=0` forces compiled Mosaic lowering
-anywhere, unset keeps the default (interpret everywhere but real TPU).
+gemm, gemv, paged attention), resolved by `kernels.mode.resolve_interpret`:
+compiled Mosaic on a TPU, interpreted on every other backend, and
+`REPRO_PALLAS_INTERPRET=1`/`=0` forces interpret/compiled anywhere. The
+resolved mode is passed explicitly so it keys each entry point's jit cache.
 """
 from __future__ import annotations
 
-import os
 from typing import Dict, Optional
 
 import jax
@@ -36,6 +36,7 @@ from repro.core.compression import CompressedTensor
 from repro.kernels import ref
 from repro.kernels.deca_decompress import decompress_pallas
 from repro.kernels.deca_gemm import decompress_gemm_pallas, decompress_gemv_pallas
+from repro.kernels.mode import resolve_interpret
 from repro.kernels.paged_attention import paged_attention_pallas
 
 # Rows at or below which the decode-shaped GeMV path is used. The decode
@@ -47,28 +48,6 @@ GEMV_MAX_M = 32
 # the PR 4 gather-read hot path (the benchmark baseline and golden
 # reference — see benchmarks/bench_serving.py).
 PAGED_ATTENTION_FUSED = True
-
-_INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
-
-
-def _use_interpret() -> bool:
-    """One switch for the Pallas compile mode of every kernel entry point.
-
-    `REPRO_PALLAS_INTERPRET=1` -> interpret everywhere (debug a real-TPU
-    miscompile against the interpreter); `=0` -> compiled Mosaic lowering
-    everywhere (the real-TPU `interpret=False` path, DESIGN.md §13);
-    unset -> interpret on every backend except real TPU."""
-    env = os.environ.get(_INTERPRET_ENV, "").strip().lower()
-    if env in ("1", "true", "yes", "on"):
-        return True
-    if env in ("0", "false", "no", "off"):
-        return False
-    if env:
-        raise ValueError(
-            f"{_INTERPRET_ENV}={env!r}: expected 1/true/yes/on or 0/false/no/off"
-        )
-    return jax.default_backend() != "tpu"
-
 
 def decompress(
     ct: CompressedTensor,
@@ -82,7 +61,7 @@ def decompress(
         return ref.decompress(ct, out_dtype=out_dtype)
     if impl == "pallas":
         return decompress_pallas(
-            ct, out_dtype=out_dtype, interpret=_use_interpret(), **block_kwargs
+            ct, out_dtype=out_dtype, interpret=resolve_interpret(), **block_kwargs
         )
     raise ValueError(impl)
 
@@ -122,12 +101,12 @@ def decompress_gemm(
     elif impl == "pallas":
         if m <= GEMV_MAX_M:
             out = decompress_gemv_pallas(
-                x2, ct, out_dtype=out_dtype, interpret=_use_interpret(),
+                x2, ct, out_dtype=out_dtype, interpret=resolve_interpret(),
                 **gemv_pl_kw,
             )
         else:
             out = decompress_gemm_pallas(
-                x2, ct, out_dtype=out_dtype, interpret=_use_interpret(),
+                x2, ct, out_dtype=out_dtype, interpret=resolve_interpret(),
                 **block_kwargs,
             )
     else:
@@ -163,6 +142,6 @@ def paged_attention(
         return paged_attention_pallas(
             q, pools, block_tables, kv_lens, q_pos,
             quant=quant, causal=causal, window=window, softcap=softcap,
-            pages_per_block=pages_per_block, interpret=_use_interpret(),
+            pages_per_block=pages_per_block, interpret=resolve_interpret(),
         )
     raise ValueError(impl)

@@ -30,7 +30,7 @@ issues no new copies for them. The dense (B, MB*bsize, Hkv, Dh) KV view of
 
 Validated against `ref.paged_decode_attention` (the jnp oracle the model
 graphs run) in tests/test_paged_attention.py; interpret mode on CPU, with
-the compile switch shared by all kernel entry points (`ops._use_interpret`,
+the compile switch shared by all kernel entry points (`kernels.mode`,
 REPRO_PALLAS_INTERPRET).
 """
 from __future__ import annotations
@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
 from repro.kernels.ref import (
     kv_decode_page,
     paged_softmax_update,
@@ -74,18 +75,18 @@ def _paged_attn_kernel(
     # windowed attention also bounds from below: pages wholly behind this
     # slot's window hold only masked keys (and are typically window-freed)
     lo_page = jnp.maximum(qpos_ref[b] - window + 1, 0) // bs if window > 0 else 0
-    q = q_ref[...].reshape(1, hkv, g, dh)
-    q_pos = jnp.reshape(qpos_ref[b], (1,))
+    q = q_ref[...].reshape(hkv, g, dh)
+    q_pos = qpos_ref[b]
 
     def fold_page(j):
         base = j * per
         kp_ref, vp_ref, pp_ref = page_refs[base : base + 3]
-        ks = page_refs[base + 3][...] if has_scale else None
-        vs = page_refs[base + 4][...] if has_scale else None
-        k = kv_decode_page(kp_ref[...], ks, quant)  # (1, bs, Hkv, Dh)
-        v = kv_decode_page(vp_ref[...], vs, quant)
+        ks = page_refs[base + 3][0] if has_scale else None
+        vs = page_refs[base + 4][0] if has_scale else None
+        k = kv_decode_page(kp_ref[0], ks, quant)  # (bs, Hkv, Dh)
+        v = kv_decode_page(vp_ref[0], vs, quant)
         m, l, acc = paged_softmax_update(
-            q, k, v, pp_ref[...], q_pos, m_s[...], l_s[...], acc_s[...],
+            q, k, v, pp_ref[0, 0], q_pos, m_s[...], l_s[...], acc_s[...],
             scale=scale, causal=causal, window=window, softcap=softcap,
         )
         m_s[...], l_s[...], acc_s[...] = m, l, acc
@@ -127,7 +128,7 @@ def paged_attention_pallas(
     window: int = 0,
     softcap: float = 0.0,
     pages_per_block: Optional[int] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused paged-attention decode over the quantized KV pool.
 
@@ -145,6 +146,10 @@ def paged_attention_pallas(
         block_tables, bs, hkv, dh, quant, hq, pages_per_block
     )
     has_scale = "ks" in pools
+    # positions ride as (pages, 1, bs): a (1, bs) block of the 2-D plane
+    # breaks Mosaic's (8, 128) block tiling, a (1, 1, bs) block of this
+    # view spans its last two dims whole
+    ppos = pools["ppos"].reshape(pools["ppos"].shape[0], 1, bs)
 
     def page_spec(shape_tail, j):
         zeros = (0,) * len(shape_tail)
@@ -161,9 +166,9 @@ def paged_attention_pallas(
         in_specs += [
             page_spec((bs, hkv, w), j),
             page_spec((bs, hkv, w), j),
-            page_spec((bs,), j),
+            page_spec((1, bs), j),
         ]
-        operands += [pools["kp"], pools["vp"], pools["ppos"]]
+        operands += [pools["kp"], pools["vp"], ppos]
         if has_scale:
             in_specs += [page_spec((bs, hkv), j), page_spec((bs, hkv), j)]
             operands += [pools["ks"], pools["vs"]]
@@ -174,9 +179,9 @@ def paged_attention_pallas(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hq, dh), lambda bb, pb, tbl, ln, qp: (bb, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, hkv, g), jnp.float32),
-            pltpu.VMEM((1, hkv, g), jnp.float32),
-            pltpu.VMEM((1, hkv, g, dh), jnp.float32),
+            pltpu.VMEM((hkv, g), jnp.float32),
+            pltpu.VMEM((hkv, g), jnp.float32),
+            pltpu.VMEM((hkv, g, dh), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -186,10 +191,10 @@ def paged_attention_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, dh), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(
         jnp.asarray(tables, jnp.int32),
         jnp.asarray(kv_lens, jnp.int32),

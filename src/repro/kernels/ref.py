@@ -7,7 +7,7 @@ stage by stage:
                        registered codec's jnp decode here),
   2. Expansion       — de-sparsification: prefix-sum over the bitmask
                        (POPCNT + parallel-prefix + crossbar in hardware;
-                       cumsum + gather here),
+                       popcount + a select per packed slot here),
   3. Scaling         — per-group scale multiply (group quantization).
 
 `paged_decode_attention` is the same idea applied to the KV stream
@@ -19,12 +19,14 @@ instead of max_blocks.
 
 Everything is jittable jnp; used as the correctness reference for the
 Pallas kernels and as the portable fallback path. Stage 1, the scale
-decode, and the KV decode route through `repro.core.codecs`, so this
-module and the Pallas kernel bodies share exactly one decode
-implementation per format.
+decode, and the KV decode route through `repro.core.codecs`, and stages
+2-3 are the kernels' own block pipeline
+(`deca_decompress.decompress_block`), so this module and the Pallas kernel
+bodies share exactly one decode implementation per format.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional
 
@@ -35,6 +37,7 @@ import numpy as np
 from repro.core.codecs import get_codec
 from repro.core.compression import CompressedTensor
 from repro.core.formats import CompressionSpec
+from repro.kernels.deca_decompress import decompress_block
 
 # Empty KV-cache slots are masked via a huge position: with causal masking
 # the sentinel exceeds every query position, and the fused path also drops
@@ -60,15 +63,9 @@ def dequant_scales(scales: jax.Array, spec: CompressionSpec) -> jax.Array:
 # stage 2 + 3: expansion (de-sparsification) and scaling
 # ---------------------------------------------------------------------------
 
-def expand_mask(mask: jax.Array, group: int) -> jax.Array:
-    """(ng, N) uint32 bitmask -> (ng, G, N) {0,1} int32 per-element bits."""
-    shifts = jnp.arange(group, dtype=jnp.uint32)[None, :, None]
-    return ((mask[:, None, :] >> shifts) & 1).astype(jnp.int32)
-
-
 def decompress(ct: CompressedTensor, out_dtype=jnp.bfloat16) -> jax.Array:
     """Full DECA pipeline: CompressedTensor -> dense (K, N) weights."""
-    return _decompress_tile(ct.codes, ct.mask, ct.scales, ct.spec).astype(
+    return decompress_block(ct.codes, ct.mask, ct.scales, ct.spec).astype(
         out_dtype
     )
 
@@ -81,26 +78,6 @@ def decompress_gemm(
     return jnp.dot(
         x.astype(jnp.bfloat16), w, preferred_element_type=jnp.float32
     ).astype(out_dtype)
-
-
-def _decompress_tile(codes, mask, scales, spec: CompressionSpec) -> jax.Array:
-    """Decompress one column tile: (ng, ck, bn) codes -> (K, bn) f32 dense.
-    Same per-element pipeline as `decompress`, restricted to `bn` columns —
-    every stage (codec decode, scale multiply, mask prefix-sum, gather) is
-    column-local, so the tile is bitwise the matching slice of the full
-    decompressed matrix."""
-    vals = get_codec(spec.quant).decode_values(codes)  # (ng, k_cap, bn)
-    if scales is not None:
-        vals = vals * get_codec(spec.quant).decode_scales(scales)[:, None, :]
-    ng, _, bn = vals.shape
-    if mask is None:
-        return vals.reshape(ng * spec.group, bn)
-    bits = expand_mask(mask, spec.group)
-    prefix = jnp.cumsum(bits, axis=1) - bits
-    idx = jnp.clip(prefix, 0, spec.k_cap - 1)
-    gathered = jnp.take_along_axis(vals, idx, axis=1)
-    dense = jnp.where(bits == 1, gathered, 0.0)
-    return dense.reshape(ng * spec.group, bn)
 
 
 GEMV_UNROLL_MAX = 8  # column tiles computed unrolled before falling to scan
@@ -153,7 +130,7 @@ def decompress_gemv(
     xb = x.astype(jnp.bfloat16)
 
     def tile(codes, mask, scales):
-        w = _decompress_tile(codes, mask, scales, spec).astype(jnp.bfloat16)
+        w = decompress_block(codes, mask, scales, spec).astype(jnp.bfloat16)
         return jnp.dot(xb, w, preferred_element_type=jnp.float32)
 
     if nb == 1:
@@ -230,28 +207,30 @@ def resolve_page_walk(
 
 
 def paged_softmax_update(
-    q: jax.Array,      # (B, Hkv, G, Dh)
-    k: jax.Array,      # (B, T, Hkv, Dh)
-    v: jax.Array,      # (B, T, Hkv, Dh)
-    k_pos: jax.Array,  # (B, T) int32; CACHE_EMPTY_POS marks empty slots
-    q_pos: jax.Array,  # (B,) int32
-    m: jax.Array,      # (B, Hkv, G) f32 running max
-    l: jax.Array,      # (B, Hkv, G) f32 running exp-sum
-    acc: jax.Array,    # (B, Hkv, G, Dh) f32 running weighted-V sum
+    q: jax.Array,      # (Hkv, G, Dh)
+    k: jax.Array,      # (T, Hkv, Dh)
+    v: jax.Array,      # (T, Hkv, Dh)
+    k_pos: jax.Array,  # (T,) int32; CACHE_EMPTY_POS marks empty slots
+    q_pos: jax.Array,  # () int32
+    m: jax.Array,      # (Hkv, G) f32 running max
+    l: jax.Array,      # (Hkv, G) f32 running exp-sum
+    acc: jax.Array,    # (Hkv, G, Dh) f32 running weighted-V sum
     *,
     scale: float,
     causal: bool,
     window: int,
     softcap: float,
 ) -> tuple:
-    """Fold one page block of KV into the online-softmax state.
+    """Fold one page block of one slot's KV into its online-softmax state.
 
+    One slot, so each contraction has the single batch dim (the KV head)
+    that Mosaic's matmul takes; the jnp oracle vmaps it over slots.
     Per-element math matches `attention_core` exactly (bf16 q·k with f32
     accumulation, softcap before the additive mask), so the renormalized
     result agrees with the gather-read reference to fp32-accumulator
     tolerance. Shared by the jnp oracle and the Pallas kernel body."""
     s = jnp.einsum(
-        "bhgd,bthd->bhgt",
+        "hgd,thd->hgt",
         q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
         preferred_element_type=jnp.float32,
     ) * scale
@@ -259,18 +238,18 @@ def paged_softmax_update(
         s = jnp.tanh(s / softcap) * softcap
     ok = k_pos != CACHE_EMPTY_POS
     if causal:
-        ok = ok & (k_pos <= q_pos[:, None])
+        ok = ok & (k_pos <= q_pos)
     if window > 0:
-        ok = ok & (k_pos > q_pos[:, None] - window)
-    s = s + jnp.where(ok, 0.0, -1e30)[:, None, None, :]
+        ok = ok & (k_pos > q_pos - window)
+    s = s + jnp.where(ok, 0.0, -1e30)
     m_new = jnp.maximum(m, s.max(axis=-1))
     # an all-masked block leaves m at the -1e30 init, where exp(s - m) is 1
     # for masked entries — their mass is therefore zeroed explicitly
-    p = jnp.exp(s - m_new[..., None]) * ok[:, None, None, :]
+    p = jnp.exp(s - m_new[..., None]) * ok
     alpha = jnp.exp(m - m_new)
     l_new = l * alpha + p.sum(axis=-1)
     pv = jnp.einsum(
-        "bhgt,bthd->bhgd", p, v, preferred_element_type=jnp.float32
+        "hgt,thd->hgd", p, v, preferred_element_type=jnp.float32
     )
     return m_new, l_new, acc * alpha[..., None] + pv
 
@@ -324,6 +303,11 @@ def paged_decode_attention(
     else:
         start = jnp.zeros((), jnp.int32)
 
+    update = functools.partial(
+        paged_softmax_update,
+        scale=scale, causal=causal, window=window, softcap=softcap,
+    )
+
     def grab(name, tbl):
         x = jnp.take(pools[name], tbl, axis=0)  # (B, ppb, bs, ...)
         return x.reshape((b, ppb * bs) + x.shape[3:])
@@ -335,9 +319,8 @@ def paged_decode_attention(
         vs = grab("vs", tbl) if has_scale else None
         k = kv_decode_page(grab("kp", tbl), ks, quant)
         v = kv_decode_page(grab("vp", tbl), vs, quant)
-        m, l, acc = paged_softmax_update(
-            qg, k, v, grab("ppos", tbl), q_pos, m, l, acc,
-            scale=scale, causal=causal, window=window, softcap=softcap,
+        m, l, acc = jax.vmap(update)(
+            qg, k, v, grab("ppos", tbl), q_pos, m, l, acc
         )
         return i + 1, m, l, acc
 

@@ -375,6 +375,11 @@ class GenerationEngine:
                 draft_params = sh.shard_params(
                     draft_params, ctx, scan_stacked=model.uniform
                 )
+        else:
+            # one upload: host (numpy) planes passed to every jitted call
+            # would be copied to the device on each of them
+            params = jax.device_put(params)
+            draft_params = jax.device_put(draft_params)
         self.params = params
         self.draft_params = draft_params
         self.max_len = max_len

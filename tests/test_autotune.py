@@ -88,6 +88,16 @@ def test_pick_blocks_decode_regime_keeps_m_whole():
     assert bn >= 2 * LANES and bn % LANES == 0
 
 
+@pytest.mark.parametrize("m", [4, 1024])
+def test_pick_blocks_unaligned_vocab_keeps_lanes_full(m):
+    """opt-66b's vocab (50272 = 2^5 x 1571) has no lane-aligned divisor:
+    block_n stays a lane multiple and the kernels grid N raggedly, instead
+    of the 32-wide divisor Mosaic refuses."""
+    bm, bn, bk = pick_blocks(m, 50272, 9216, get_spec("mxfp4_100"))
+    assert bn % LANES == 0 and bn >= LANES
+    assert 9216 % bk == 0 and m % bm == 0
+
+
 def test_pick_blocks_shrinks_k_first_under_vmem_pressure():
     spec = get_spec("bf8_50")
     full = pick_blocks(128, 4096, 4096, spec)

@@ -144,3 +144,20 @@ def test_exact_byte_accounting():
         spec = get_spec(name)
         ct = compress(w, spec)
         assert ct.nbytes == spec.bytes_for(256, 48), name
+
+
+@pytest.mark.parametrize("name", ["mxfp4_100", "bf8_50", "int4_25"])
+def test_parallel_slabs_match_one_pass(name):
+    """A matrix large enough to be compressed as column slabs on threads
+    gives planes bit-identical to one pass over the whole matrix."""
+    from repro.core import compression
+
+    spec = get_spec(name)
+    w = np.random.default_rng(4).standard_normal((256, 16384)).astype(np.float32)
+    assert w.size >= compression._PARALLEL_MIN
+    got, want = compress(w, spec), compression._compress_cols(w, spec)
+    for plane in ("codes", "mask", "scales"):
+        a, b = getattr(got, plane), getattr(want, plane)
+        assert (a is None) == (b is None), plane
+        if a is not None:
+            np.testing.assert_array_equal(a, b, err_msg=plane)

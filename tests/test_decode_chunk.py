@@ -10,6 +10,7 @@ the full-matrix reference."""
 import numpy as np
 import pytest
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from repro.configs.base import get_smoke_config
@@ -201,12 +202,12 @@ def _eqn_avals(jaxpr):
         for p in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
                 p, is_leaf=lambda x: isinstance(
-                    x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)
+                    x, (jax.extend.core.Jaxpr, jax.extend.core.ClosedJaxpr)
                 )
             ):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
                     yield from _eqn_avals(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jax.extend.core.Jaxpr):
                     yield from _eqn_avals(sub)
 
 

@@ -13,6 +13,7 @@ from repro.core.formats import get_spec
 from repro.dist import sharding as sh
 from repro.dist.fault import FaultInjector, InjectedFault, StragglerWatchdog
 from repro.dist.grad_compression import make_compressed_allreduce
+from repro.launch.mesh import make_mesh
 
 
 class Ctx:
@@ -98,7 +99,7 @@ def test_opt_spec_tree_adafactor_factored():
 def test_compressed_allreduce_error_feedback_reduces_bias():
     """Over repeated steps, error feedback keeps the accumulated average
     near the true gradient sum; naive quantization accumulates bias."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     # one outlier per group forces a coarse scale -> visible per-step bias
     g_np = np.full((128,), 0.03, np.float32)
     g_np[0] = 1.0
@@ -122,7 +123,7 @@ def test_compressed_allreduce_error_feedback_reduces_bias():
 
 
 def test_compressed_allreduce_bf8_method():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     g = {"w": jnp.asarray(
         np.random.default_rng(3).standard_normal((64,)).astype(np.float32)
     )}
@@ -150,7 +151,7 @@ def test_grad_compression_threads_through_train_loop():
 
     cfg = get_smoke_config("llama3.2-1b")
     model = Model(cfg)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from repro.optim.optimizers import AdamW
 
     opt = AdamW(lr=3e-3, weight_decay=0.0)

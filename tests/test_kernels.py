@@ -63,6 +63,22 @@ def test_gemm_block_shape_sweep(blocks):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
 
+@pytest.mark.parametrize("fmt", ["mxfp4_100", "bf8_50", "int4_25"])
+@pytest.mark.parametrize("m", [4, 40])
+def test_ragged_n_matches_oracle(fmt, m):
+    """N with no lane-aligned divisor (like a 50272 vocab) runs lane-aligned
+    blocks with a ragged last one; its out-of-range columns are dropped."""
+    from repro.kernels import ops
+
+    k, n = 64, 300
+    _, ct = _compress(k, n, fmt, seed=5)
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((m, k)), jnp.float32)
+    want = ref.decompress_gemm(x, ct)
+    got = ops.decompress_gemm(x, ct, impl="pallas")
+    assert got.shape == (m, n)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+
+
 def test_decompress_output_dtype():
     _, ct = _compress(64, 32, "bf8_100")
     out = decompress_pallas(ct, out_dtype=jnp.bfloat16, interpret=True)

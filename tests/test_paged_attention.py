@@ -15,12 +15,13 @@ import types
 import numpy as np
 import pytest
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from repro.configs.base import get_smoke_config
 from repro.core import roofsurface as rs
 from repro.core.codecs import kv_codec_names
-from repro.kernels import ops
+from repro.kernels import mode, ops
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.models.layers import (
     CACHE_EMPTY_POS,
@@ -335,12 +336,12 @@ def _eqn_avals(jaxpr):
         for p in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
                 p, is_leaf=lambda x: isinstance(
-                    x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)
+                    x, (jax.extend.core.Jaxpr, jax.extend.core.ClosedJaxpr)
                 )
             ):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
                     yield from _eqn_avals(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jax.extend.core.Jaxpr):
                     yield from _eqn_avals(sub)
 
 
@@ -386,22 +387,23 @@ def test_decode_chunk_never_materializes_gathered_kv():
 
 def test_interpret_env_switch(monkeypatch):
     for val in ("1", "true", "YES", "on"):
-        monkeypatch.setenv(ops._INTERPRET_ENV, val)
-        assert ops._use_interpret() is True
+        monkeypatch.setenv(mode.INTERPRET_ENV, val)
+        assert mode.resolve_interpret() is True
+        assert mode.resolve_interpret(False) is False  # explicit flag wins
     for val in ("0", "false", "No", "off"):
-        monkeypatch.setenv(ops._INTERPRET_ENV, val)
-        assert ops._use_interpret() is False
-    monkeypatch.setenv(ops._INTERPRET_ENV, "definitely")
+        monkeypatch.setenv(mode.INTERPRET_ENV, val)
+        assert mode.resolve_interpret() is False
+    monkeypatch.setenv(mode.INTERPRET_ENV, "definitely")
     with pytest.raises(ValueError, match="REPRO_PALLAS_INTERPRET"):
-        ops._use_interpret()
-    monkeypatch.delenv(ops._INTERPRET_ENV)
-    assert ops._use_interpret() is (jax.default_backend() != "tpu")
+        mode.resolve_interpret()
+    monkeypatch.delenv(mode.INTERPRET_ENV)
+    assert mode.resolve_interpret() is (jax.default_backend() != "tpu")
 
 
 def test_interpret_env_honored_by_paged_attention(monkeypatch):
     """The forced-interpret override flows through the paged-attention
-    entry point (the other three kernel entries share `_use_interpret`)."""
-    monkeypatch.setenv(ops._INTERPRET_ENV, "1")
+    entry point (the other three kernel entries share `resolve_interpret`)."""
+    monkeypatch.setenv(mode.INTERPRET_ENV, "1")
     pool, tables, q, q_pos, kv_lens = _case("none", lengths=(3, 9))
     out = ops.paged_attention(
         q[:, 0], pool, tables, kv_lens, q_pos, quant="none", impl="pallas"

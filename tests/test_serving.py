@@ -90,6 +90,30 @@ def test_generation_engine_shapes(llama):
     assert (out >= 0).all() and (out < m.cfg.vocab_size).all()
 
 
+def test_engine_holds_weights_on_device(llama):
+    """mesh=None: the engine uploads every CompressedTensor plane, draft
+    tree included, once at construction — no numpy plane rides along to
+    be re-copied on each jitted call."""
+    from repro.serve.engine import SpecConfig
+
+    m, params = llama
+    c = compress_tree(params, get_spec("int4_25"))
+    eng = GenerationEngine(
+        m, c, max_len=32, spec_decode=SpecConfig(k=2, draft_codec="bf8_50")
+    )
+    for tree in (eng.params, eng.draft_params):
+        cts = [
+            l for l in jax.tree_util.tree_leaves(
+                tree, is_leaf=lambda x: isinstance(x, CompressedTensor)
+            ) if isinstance(l, CompressedTensor)
+        ]
+        assert cts
+        for ct in cts:
+            assert ct.mask is not None  # both codecs here are sparse
+            for plane in (ct.codes, ct.mask, ct.scales):
+                assert plane is None or isinstance(plane, jax.Array), type(plane)
+
+
 def test_generation_deterministic_greedy(llama):
     m, params = llama
     prompts = np.array([[3, 1, 4, 1, 5, 9]], np.int32)

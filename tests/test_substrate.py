@@ -11,6 +11,7 @@ from repro.checkpoint.ckpt import Checkpointer
 from repro.configs.base import ShapeConfig, get_smoke_config
 from repro.data.pipeline import SyntheticPipeline
 from repro.dist.fault import FaultInjector, ResilientTrainer, StragglerWatchdog
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.optim.optimizers import AdamW, Adafactor, warmup_cosine
 from repro.train.trainer import build_optimizer, make_train_step
@@ -140,7 +141,7 @@ def test_grad_compression_int8_error_feedback():
     error feedback accumulates the residual (compensates over steps)."""
     from repro.dist.grad_compression import make_compressed_allreduce
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     g = {"w": jnp.asarray(
         np.random.default_rng(0).standard_normal((512,)).astype(np.float32)
     )}

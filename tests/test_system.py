@@ -11,6 +11,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ShapeConfig, get_smoke_config
 from repro.data.pipeline import SyntheticPipeline
 from repro.dist import sharding as sh
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.train.trainer import build_optimizer, make_train_step
 
@@ -37,7 +38,7 @@ def test_loss_decreases_end_to_end():
 
 def test_divisibility_fallback():
     """8 heads on a 16-way model axis must NOT shard the head dim."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # fake a 16-wide model axis via the ctx (rules only read axis sizes)
     ctx = sh.ShardingCtx(mesh)
     spec = sh.spec_for((8, 128), ("model", "none"), ctx)
@@ -59,7 +60,7 @@ def test_param_specs_respect_rules():
     cfg = get_smoke_config("llama3-8b")
     model = Model(cfg)
     aparams = jax.eval_shape(lambda k: model.init(k), jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = sh.ShardingCtx(mesh, fsdp=True)
     specs = sh.param_spec_tree(aparams, ctx, scan_stacked=model.uniform)
     # norms replicated; stacked block weights have leading None
@@ -75,7 +76,7 @@ def test_distributed_train_step_small_mesh():
         pytest.skip("needs >=2 devices (run under XLA_FLAGS host device count)")
     cfg = get_smoke_config("llama3.2-1b")
     model = Model(cfg)
-    mesh = jax.make_mesh((2, 1), ("data", "model"))
+    mesh = make_mesh((2, 1), ("data", "model"))
     pipe = SyntheticPipeline(cfg, ShapeConfig("t", "train", 16, 4), seed=2)
     opt = build_optimizer(cfg)
     with sh.use_mesh(mesh) as ctx:
@@ -97,7 +98,7 @@ def test_dryrun_cell_reduced():
     )
     n = jax.device_count()
     orig = dryrun.make_production_mesh
-    dryrun.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
+    dryrun.make_production_mesh = lambda multi_pod=False: make_mesh(
         (1, n), ("data", "model")
     )
     try:
@@ -107,3 +108,25 @@ def test_dryrun_cell_reduced():
         dryrun.make_production_mesh = orig
     assert r["status"] == "OK", r.get("error")
     assert r["hlo_flops"] > 0 and r["bottleneck"] in ("MEM", "MTX", "ICI")
+
+
+def test_compile_cache_has_one_fixed_place(monkeypatch, tmp_path):
+    """The persistent compilation cache sits at `<checkout>/.jax_cache`
+    unless JAX_COMPILATION_CACHE_DIR places it, in which case the helper
+    sets nothing (JAX reads the variable itself)."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache as cc
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv(cc.CACHE_ENV, raising=False)
+        checkout = Path(__file__).resolve().parents[1]
+        assert cc.use_compile_cache() == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(checkout / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", prev)
+        monkeypatch.setenv(cc.CACHE_ENV, str(tmp_path))
+        assert cc.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
