@@ -24,6 +24,13 @@ gemm, gemv, paged attention), resolved by `kernels.mode.resolve_interpret`:
 compiled Mosaic on a TPU, interpreted on every other backend, and
 `REPRO_PALLAS_INTERPRET=1`/`=0` forces interpret/compiled anywhere. The
 resolved mode is passed explicitly so it keys each entry point's jit cache.
+
+Each entry point's dispatch runs inside a `jax.named_scope` that names the
+kernel, not its implementation (`deca.gemv`, `deca.gemm`,
+`deca.paged_attention`, `deca.decompress`): the scope lands in the HLO
+metadata of every op either impl emits, so a device trace attributes the
+kernel's time by the same name before and after a kernel swap
+(DESIGN.md §14).
 """
 from __future__ import annotations
 
@@ -49,6 +56,12 @@ GEMV_MAX_M = 32
 # reference — see benchmarks/bench_serving.py).
 PAGED_ATTENTION_FUSED = True
 
+# Device-trace names of the kernels (see the module docstring).
+SCOPE_GEMV = "deca.gemv"
+SCOPE_GEMM = "deca.gemm"
+SCOPE_PAGED_ATTENTION = "deca.paged_attention"
+SCOPE_DECOMPRESS = "deca.decompress"
+
 def decompress(
     ct: CompressedTensor,
     *,
@@ -57,12 +70,14 @@ def decompress(
     **block_kwargs,
 ) -> jax.Array:
     """Decompress to a dense (K, N) array. impl: 'ref' | 'pallas'."""
-    if impl == "ref":
-        return ref.decompress(ct, out_dtype=out_dtype)
-    if impl == "pallas":
-        return decompress_pallas(
-            ct, out_dtype=out_dtype, interpret=resolve_interpret(), **block_kwargs
-        )
+    with jax.named_scope(SCOPE_DECOMPRESS):
+        if impl == "ref":
+            return ref.decompress(ct, out_dtype=out_dtype)
+        if impl == "pallas":
+            return decompress_pallas(
+                ct, out_dtype=out_dtype, interpret=resolve_interpret(),
+                **block_kwargs,
+            )
     raise ValueError(impl)
 
 
@@ -91,27 +106,25 @@ def decompress_gemm(
     gemv_pl_kw = {
         k: v for k, v in block_kwargs.items() if k in ("block_n", "block_k")
     }
-    if impl == "gemv":
-        out = ref.decompress_gemv(x2, ct, out_dtype=out_dtype, **gemv_ref_kw)
-    elif impl == "ref":
-        if m <= GEMV_MAX_M:
-            out = ref.decompress_gemv(x2, ct, out_dtype=out_dtype, **gemv_ref_kw)
-        else:
-            out = ref.decompress_gemm(x2, ct, out_dtype=out_dtype)
-    elif impl == "pallas":
-        if m <= GEMV_MAX_M:
+    if impl not in ("gemv", "ref", "pallas"):
+        raise ValueError(impl)
+    gemv = impl == "gemv" or m <= GEMV_MAX_M
+    with jax.named_scope(SCOPE_GEMV if gemv else SCOPE_GEMM):
+        if impl == "pallas" and gemv:
             out = decompress_gemv_pallas(
                 x2, ct, out_dtype=out_dtype, interpret=resolve_interpret(),
                 **gemv_pl_kw,
             )
-        else:
+        elif impl == "pallas":
             out = decompress_gemm_pallas(
                 x2, ct, out_dtype=out_dtype, interpret=resolve_interpret(),
                 **block_kwargs,
             )
-    else:
-        raise ValueError(impl)
-    return out.reshape(*lead, out.shape[-1])
+        elif gemv:
+            out = ref.decompress_gemv(x2, ct, out_dtype=out_dtype, **gemv_ref_kw)
+        else:
+            out = ref.decompress_gemm(x2, ct, out_dtype=out_dtype)
+        return out.reshape(*lead, out.shape[-1])
 
 
 def paged_attention(
@@ -132,16 +145,17 @@ def paged_attention(
     inside the page walk, online softmax, length-bounded by `kv_lens` —
     the gathered dense KV view is never materialized. impl: 'ref' (the
     length-bounded while-loop oracle the model graphs run) | 'pallas'."""
-    if impl == "ref":
-        return ref.paged_decode_attention(
-            q, pools, block_tables, kv_lens, q_pos,
-            quant=quant, causal=causal, window=window, softcap=softcap,
-            pages_per_block=pages_per_block,
-        )
-    if impl == "pallas":
-        return paged_attention_pallas(
-            q, pools, block_tables, kv_lens, q_pos,
-            quant=quant, causal=causal, window=window, softcap=softcap,
-            pages_per_block=pages_per_block, interpret=resolve_interpret(),
-        )
+    with jax.named_scope(SCOPE_PAGED_ATTENTION):
+        if impl == "ref":
+            return ref.paged_decode_attention(
+                q, pools, block_tables, kv_lens, q_pos,
+                quant=quant, causal=causal, window=window, softcap=softcap,
+                pages_per_block=pages_per_block,
+            )
+        if impl == "pallas":
+            return paged_attention_pallas(
+                q, pools, block_tables, kv_lens, q_pos,
+                quant=quant, causal=causal, window=window, softcap=softcap,
+                pages_per_block=pages_per_block, interpret=resolve_interpret(),
+            )
     raise ValueError(impl)

@@ -29,6 +29,11 @@ from repro.kernels import ops as kernel_ops
 
 Params = Dict[str, Any]
 
+# Device-trace names (`jax.named_scope`) of the paged attention layer's
+# work outside the DECA kernels (DESIGN.md §14).
+SCOPE_KV_WRITE = "kv.write"
+SCOPE_ATTENTION = "model.attention"
+
 
 def _dense_init(key, shape, scale_axis=0, dtype=jnp.float32):
     fan_in = shape[scale_axis]
@@ -501,10 +506,11 @@ def paged_attention_block(
     else:
         tok_pos = positions if positions.ndim == 2 else positions[0]
 
-    new_cache = paged_update_cache(
-        cache, k, v, write_pos, write_slots, fresh_pages, copy_pages,
-        quant=cfg.kv_quant,
-    )
+    with jax.named_scope(SCOPE_KV_WRITE):
+        new_cache = paged_update_cache(
+            cache, k, v, write_pos, write_slots, fresh_pages, copy_pages,
+            quant=cfg.kv_quant,
+        )
     window = cfg.window if local else 0
     if window_override:
         # spec-decode draft passes: a sliding-window cap trades a little
@@ -519,16 +525,17 @@ def paged_attention_block(
         )
         out = att[:, None]  # (B, 1, Hq, Dh)
     else:
-        k_all, v_all, k_pos = paged_gather_kv(
-            new_cache, block_tables, quant=cfg.kv_quant
-        )
-        k_all, v_all = constrain(k_all, "bshd"), constrain(v_all, "bshd")
-        out = attention_core(
-            q, k_all, v_all,
-            q_pos=tok_pos, k_pos=k_pos,
-            causal=cfg.causal, window=window,
-            softcap=cfg.attn_softcap,
-        )
+        with jax.named_scope(SCOPE_ATTENTION):
+            k_all, v_all, k_pos = paged_gather_kv(
+                new_cache, block_tables, quant=cfg.kv_quant
+            )
+            k_all, v_all = constrain(k_all, "bshd"), constrain(v_all, "bshd")
+            out = attention_core(
+                q, k_all, v_all,
+                q_pos=tok_pos, k_pos=k_pos,
+                causal=cfg.causal, window=window,
+                softcap=cfg.attn_softcap,
+            )
     out = constrain(out, "bshd")
     return mm(out.reshape(b, s, hq * dh), params["wo"]), new_cache
 

@@ -30,6 +30,9 @@ from repro.models import layers as L
 
 Params = Dict[str, Any]
 
+# Device-trace name (`jax.named_scope`) of the LM head matmul (DESIGN.md §14).
+SCOPE_HEAD = "model.head"
+
 
 def _kind_layout(cfg: ModelConfig) -> Tuple[str, ...]:
     return cfg.layer_kinds()
@@ -326,14 +329,16 @@ class Model:
                     new_cache[str(i)] = nc
 
         x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = jnp.einsum(
-                "bsd,vd->bsv", x, params["embed"], preferred_element_type=jnp.float32
-            )
-        else:
-            from repro.core.decompress import mm
+        with jax.named_scope(SCOPE_HEAD):
+            if cfg.tie_embeddings:
+                logits = jnp.einsum(
+                    "bsd,vd->bsv", x, params["embed"],
+                    preferred_element_type=jnp.float32,
+                )
+            else:
+                from repro.core.decompress import mm
 
-            logits = mm(x.astype(jnp.float32), params["lm_head"])
+                logits = mm(x.astype(jnp.float32), params["lm_head"])
         if cfg.final_softcap:
             logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
         logits = constrain(logits, "btv")

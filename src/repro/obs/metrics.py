@@ -132,9 +132,9 @@ class MetricsRegistry:
 
     Naming convention (DESIGN.md §14): dotted lowercase paths,
     `<subsystem>.<object>.<observable>[_<unit>]` — e.g.
-    `serve.prefill.wall_s`, `serve.pool.used_pages`, `rooflens.decode.ratio`.
-    Re-requesting a name with a conflicting type or unit raises: one name,
-    one meaning, for the whole process.
+    `serve.tier.restore_wall_s`, `serve.pool.used_pages`,
+    `rooflens.decode.ratio`. Re-requesting a name with a conflicting type
+    or unit raises: one name, one meaning, for the whole process.
     """
 
     def __init__(self, clock: Optional[Clock] = None):

@@ -1,4 +1,11 @@
-"""Per-request lifecycle tracing for the continuous-batching scheduler.
+"""Serving-loop spans and per-request lifecycle tracing.
+
+`Span` is the serving loop's one span site (DESIGN.md §14), with two sinks:
+every span is a `jax.profiler.TraceAnnotation`, so a profile shows it on
+the same clock as the device's events (and the work it launched as its
+arguments); and where a collector needs times (the `Tracer`, RoofLens) the
+span also reads them on the observability clock, from which the
+scheduler's `on_*` hooks below take their spans.
 
 Event taxonomy (DESIGN.md §14): a request's life is
 `submit -> admit(slot) -> prefill -> decode chunk* -> finish(eos|length)`.
@@ -29,9 +36,48 @@ import math
 import time
 from typing import Callable, Dict, IO, List, Optional, Union
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import exact_percentiles
 
 Clock = Callable[[], float]
+
+
+class Span:
+    """One named span of the serving loop (module docstring).
+
+    Entering opens a profiler annotation, which costs about a microsecond
+    and records nothing unless a profile is being taken. With a `clock`,
+    `t0`/`t1` are read on entry and exit. `profiling()` says whether a
+    profile is active: the work arguments given to `annotate` are computed
+    only then."""
+
+    __slots__ = ("_ann", "_clock", "t0", "t1")
+
+    def __init__(self, name: str, clock: Optional[Clock] = None):
+        self._ann = TraceAnnotation(name)
+        self._clock = clock
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        if self._clock is not None:
+            self.t0 = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._clock is not None:
+            self.t1 = self._clock()
+        self._ann.__exit__(*exc)
+
+    @staticmethod
+    def profiling() -> bool:
+        return TraceAnnotation.is_enabled()
+
+    def annotate(self, **args: int) -> None:
+        """Attach `args` to the span in the profile."""
+        self._ann.set_metadata(**args)
+
 
 # scheduler-track span names (chrome trace `name` field)
 SPAN_ADMIT = "admit"

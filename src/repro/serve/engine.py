@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.dist import sharding as sh
 from repro.models.model import Model
+from repro.obs.trace import Span
 from repro.serve.host_tier import HostTier, apply_page_planes
 from repro.serve.paged_cache import PagedKVCache
 from repro.serve.scheduler import Scheduler
@@ -625,7 +626,8 @@ class GenerationEngine:
         rids, start_steps, max_steps, eos, active,
     ):
         """One device-resident chunk: only the sampled (C, M) token ids
-        cross back to host — a single synchronization per `chunk` tokens."""
+        cross back to host — a single synchronization per `chunk` tokens,
+        which the scheduler makes (returned on device)."""
         with self._mesh_scope():
             toks, self.kv.pools = self._paged_decode_chunk(
                 self.params,
@@ -646,7 +648,7 @@ class GenerationEngine:
                 self._base_key,
                 greedy=self.temperature <= 0.0,
             )
-        return np.asarray(toks)
+        return toks
 
     def _run_paged_spec_chunk(
         self, tokens0, tables, p0, fresh, rids, start_steps, max_steps, eos,
@@ -654,7 +656,7 @@ class GenerationEngine:
     ):
         """One device-resident spec chunk: `spec_rounds` draft/verify rounds;
         only the packed emitted tokens and per-round emission counts cross
-        back to host."""
+        back to host (returned on device; the scheduler syncs)."""
         with self._mesh_scope():
             out, e_rounds, self.kv.pools = self._paged_spec_chunk(
                 self.params,
@@ -673,7 +675,7 @@ class GenerationEngine:
                 self._base_key,
                 greedy=self.temperature <= 0.0,
             )
-        return np.asarray(out), np.asarray(e_rounds)
+        return out, e_rounds
 
     def submit(
         self,
@@ -690,10 +692,11 @@ class GenerationEngine:
         served; priority orders park-victim selection under pool pressure."""
         if not self.paged:
             raise RuntimeError("request-level API requires the paged engine")
-        return self.scheduler.submit(
-            prompt, max_new_tokens=max_new_tokens, eos_id=eos_id,
-            deadline_s=deadline_s, priority=priority,
-        )
+        with Span("serve.submit"):
+            return self.scheduler.submit(
+                prompt, max_new_tokens=max_new_tokens, eos_id=eos_id,
+                deadline_s=deadline_s, priority=priority,
+            )
 
     @property
     def statuses(self) -> Dict[int, Any]:

@@ -50,6 +50,12 @@ index pages to host memory instead of dropping them), admission restores
 tier-resident prefix hits through checksum-verified uploads drained just
 before the prefill launch (`tier_restore_fn`), and `check_invariants`
 audits the tiered pages as a fourth conservation class.
+
+Spans (DESIGN.md §14): `serve.step` covers a round; inside it
+`serve.admit`, `serve.prefill` (`.pack`, `.launch`, `.sync`) and
+`serve.decode` (`.pack`, `.launch`, `.sync`, `.replay`) name the host's
+phases on the profiler's clock, and `serve.prefill` / `serve.decode` carry
+the work their launch gives the device, counted from the host arrays.
 """
 from __future__ import annotations
 
@@ -63,6 +69,7 @@ import numpy as np
 
 from repro.kernels import ops as kernel_ops
 from repro.models.layers import CACHE_EMPTY_POS
+from repro.obs.trace import Span
 from repro.serve.paged_cache import PagedKVCache
 from repro.serve.slo import LADDER, RequestStatus, SLAPolicy
 
@@ -188,7 +195,8 @@ class Scheduler:
     decode_chunk_fn(tokens0 (M,1), tables (M,MB), positions (C,M,1),
                     write_slots (C,M,1), write_pos (C,M,1), fresh (C,F),
                     kv_lens (C,M), rids (M,), start_steps (M,),
-                    max_steps (M,), eos (M,), active (M,)) -> np tokens (C, M)
+                    max_steps (M,), eos (M,), active (M,)) -> tokens (C, M),
+                    on device or host (the scheduler's sync reads them)
     sample_fn(logits (N,V) on device, rids (N,), steps (N,)) -> np tokens (N,)
 
     `kv_lens` is the per-slot length vector of DESIGN.md §13 — the block
@@ -220,8 +228,9 @@ class Scheduler:
     `spec_fn` (DESIGN.md §16) replaces the decode chunk with speculative
     rounds: spec_fn(tokens0 (M,1), tables (M,TW), p0 (M,), fresh (F,),
     rids, start_steps, max_steps, eos, active) -> (out (cap, M) packed
-    emissions, e_rounds (rounds, M)); `spec_k`/`spec_rounds`/`spec_window`
-    mirror the engine's SpecConfig for accounting. `prefill_sla_s` plus an
+    emissions, e_rounds (rounds, M)), on device or host as above;
+    `spec_k`/`spec_rounds`/`spec_window` mirror the engine's SpecConfig
+    for accounting. `prefill_sla_s` plus an
     installed RoofLens switches the chunked-prefill span from the fixed
     `prefill_chunk` to the largest predicted-to-fit ladder step (see
     `_prefill_span_cap`).
@@ -334,10 +343,13 @@ class Scheduler:
         self._obs_metrics = obs.metrics if obs is not None else None
         self._obs_tracer = obs.tracer if obs is not None else None
         self._obs_rooflens = obs.rooflens if obs is not None else None
-        self._obs_clock = obs.clock if obs is not None else None
+        obs_clock = obs.clock if obs is not None else None
         # deadlines / TTFT gating need a clock even without observability;
         # share the obs clock when installed so trace timestamps line up
-        self._clock = self._obs_clock or time.monotonic
+        self._clock = obs_clock or time.monotonic
+        # spans read the clock only for the collectors that time them
+        timed = self._obs_tracer is not None or self._obs_rooflens is not None
+        self._span_clock = obs_clock if timed else None
 
     # ------------------------------------------------------------------
     # request API
@@ -414,6 +426,10 @@ class Scheduler:
     # one scheduling round: admission -> batched prefill -> chunked decode
     # ------------------------------------------------------------------
     def step(self) -> None:
+        with Span("serve.step"):
+            self._step()
+
+    def _step(self) -> None:
         t0 = time.monotonic() if self._watchdog is not None else 0.0
         chaos_pages: List[int] = []
         if self._injector is not None:
@@ -461,46 +477,45 @@ class Scheduler:
         return len(r.prompt) + (r.max_new_tokens - len(r.done_tokens)) - 1
 
     def _admit(self) -> None:
-        t0 = self._obs_clock() if self._obs_tracer is not None else 0.0
-        self._expire_queued()
-        admitted: List[tuple] = []
-        blocked = False  # pool pressure (not SLO deferral) stalled the head
-        for slot in range(self.max_slots):
-            if self.slots[slot] is not None:
-                continue
-            r = self._next_candidate()
-            if r is None:
-                break
-            if self._itl_defer(r):
-                break
-            if not self.cache.can_admit(self._kv_len(r), r.prompt):
-                blocked = True
-                break  # FIFO: don't let short requests starve the head
-            self.queue.popleft()
-            r.prefilled = self.cache.admit(
-                r.rid, self._kv_len(r), prompt=r.prompt
-            )
-            if r.was_parked:
-                r.was_parked = False
-                self._stats["resumed_requests"] += 1
-                if self._obs_metrics is not None:
-                    self._obs_metrics.counter(
-                        "serve.requests.resumed", unit="events"
-                    ).inc()
-            self.slots[slot] = r
-            admitted.append((slot, r))
-        if self.sla is not None:
-            if blocked and self.queue:
-                self._degrade()
-            elif not self.queue and self.degradation_level:
-                # backlog drained: restore full capability (DESIGN.md §17)
-                self._relax()
+        with Span("serve.admit", self._span_clock) as span:
+            self._expire_queued()
+            admitted: List[tuple] = []
+            blocked = False  # pool pressure (not SLO deferral) stalled the head
+            for slot in range(self.max_slots):
+                if self.slots[slot] is not None:
+                    continue
+                r = self._next_candidate()
+                if r is None:
+                    break
+                if self._itl_defer(r):
+                    break
+                if not self.cache.can_admit(self._kv_len(r), r.prompt):
+                    blocked = True
+                    break  # FIFO: don't let short requests starve the head
+                self.queue.popleft()
+                r.prefilled = self.cache.admit(
+                    r.rid, self._kv_len(r), prompt=r.prompt
+                )
+                if r.was_parked:
+                    r.was_parked = False
+                    self._stats["resumed_requests"] += 1
+                    if self._obs_metrics is not None:
+                        self._obs_metrics.counter(
+                            "serve.requests.resumed", unit="events"
+                        ).inc()
+                self.slots[slot] = r
+                admitted.append((slot, r))
+            if self.sla is not None:
+                if blocked and self.queue:
+                    self._degrade()
+                elif not self.queue and self.degradation_level:
+                    # backlog drained: restore full capability (DESIGN.md §17)
+                    self._relax()
         if self._obs_tracer is not None and admitted:
-            t1 = self._obs_clock()
             for slot, r in admitted:
                 self._obs_tracer.on_admit(r.rid, slot)
             self._obs_tracer.on_admit_round(
-                t0, t1, len(admitted), len(self.queue)
+                span.t0, span.t1, len(admitted), len(self.queue)
             )
         if self._obs_metrics is not None and admitted:
             self._obs_metrics.counter(
@@ -910,162 +925,170 @@ class Scheduler:
         (the first output); the others' logits rows are discarded. Finished
         prompts are inserted into the prefix index here, while their full
         pages are still position-contiguous."""
-        bs = self.cache.block_size
-        nrows = len(rows)
-        pages = max(math.ceil(n / bs) for _, _, _, n in rows)
-        if bucketed:
-            # batch rides power-of-two buckets; the span stays at the exact
-            # page count (<= max_blocks shapes, same as the per-request
-            # path) — padding rows are cheap, padded columns are not
-            b = min(_pow2ceil(nrows), self.max_slots)
-        else:
-            b = nrows
-        sp = pages * bs
-        if bounded:
-            tw = min(
-                _pow2ceil(max(
-                    math.ceil((start + n) / bs) for _, _, start, n in rows
-                )),
-                self.max_blocks,
-            )
-        else:
-            tw = self.max_blocks
+        with Span("serve.prefill") as span:
+            with Span("serve.prefill.pack"):
+                bs = self.cache.block_size
+                nrows = len(rows)
+                pages = max(math.ceil(n / bs) for _, _, _, n in rows)
+                if bucketed:
+                    # batch rides power-of-two buckets; the span stays at
+                    # the exact page count (<= max_blocks shapes, same as the
+                    # per-request path) — padding rows are cheap, padded
+                    # columns are not
+                    b = min(_pow2ceil(nrows), self.max_slots)
+                else:
+                    b = nrows
+                sp = pages * bs
+                if bounded:
+                    furthest = max(
+                        math.ceil((start + n) / bs) for _, _, start, n in rows
+                    )
+                    tw = min(_pow2ceil(furthest), self.max_blocks)
+                else:
+                    tw = self.max_blocks
 
-        tokens = np.zeros((b, sp), np.int32)
-        positions = np.broadcast_to(
-            np.arange(sp, dtype=np.int32), (b, sp)
-        ).copy()
-        write_pos = np.full((b, sp), CACHE_EMPTY_POS, np.int32)
-        write_slots = np.broadcast_to(
-            self.cache.null_slots(np.arange(sp)), (b, sp)
-        ).copy()
-        tables = np.zeros((b, tw), np.int32)
-        last_idx = np.zeros(b, np.int32)
-        rids = np.full(b, -1, np.int64)
-        steps0 = np.zeros(b, np.int64)
-        completing: List[tuple] = []  # (row, slot, r) sampling their 1st token
-        for row, (slot, r, start, n) in enumerate(rows):
-            tokens[row, :n] = r.prompt[start:start + n]
-            positions[row] = start + positions[row]
-            write_pos[row, :n] = np.arange(start, start + n, dtype=np.int32)
-            write_slots[row, :n] = self.cache.write_slots(r.rid, start, n)
-            tables[row] = self.cache.block_table_row(r.rid, tw)
-            r.prefilled = start + n
-            if r.prefilled >= len(r.prompt):
-                last_idx[row] = n - 1
-                rids[row] = r.rid
-                # a resume prefill's sample is the request's next *global*
-                # output token, so it keys on the banked count — this is
-                # what makes park/resume bit-identical (DESIGN.md §17)
-                steps0[row] = len(r.done_tokens)
-                completing.append((row, slot, r))
-        copies = self.cache.drain_copies(b)
-        fresh_rows = self.cache.drain_fresh_rows(b * pages)
-        for extra in fresh_rows[1:]:
-            # more recycled pages than the launch's fresh vector carries
-            # (long-prompt burst / unaligned chunk boundaries): scrub the
-            # overflow in dedicated fixed-shape calls *before* the launch
-            # that writes into those pages
-            if self._scrub is None:
-                raise ValueError(
-                    f"{sum(int((fr != 0).sum()) for fr in fresh_rows)} fresh "
-                    f"pages > pad_to={b * pages} and no scrub_fn installed"
+                tokens = np.zeros((b, sp), np.int32)
+                positions = np.broadcast_to(
+                    np.arange(sp, dtype=np.int32), (b, sp)
+                ).copy()
+                write_pos = np.full((b, sp), CACHE_EMPTY_POS, np.int32)
+                write_slots = np.broadcast_to(
+                    self.cache.null_slots(np.arange(sp)), (b, sp)
+                ).copy()
+                tables = np.zeros((b, tw), np.int32)
+                last_idx = np.zeros(b, np.int32)
+                rids = np.full(b, -1, np.int64)
+                steps0 = np.zeros(b, np.int64)
+                # (row, slot, r) of the rows sampling their first token
+                completing: List[tuple] = []
+                for row, (slot, r, start, n) in enumerate(rows):
+                    tokens[row, :n] = r.prompt[start:start + n]
+                    positions[row] = start + positions[row]
+                    write_pos[row, :n] = np.arange(
+                        start, start + n, dtype=np.int32
+                    )
+                    write_slots[row, :n] = self.cache.write_slots(
+                        r.rid, start, n
+                    )
+                    tables[row] = self.cache.block_table_row(r.rid, tw)
+                    r.prefilled = start + n
+                    if r.prefilled >= len(r.prompt):
+                        last_idx[row] = n - 1
+                        rids[row] = r.rid
+                        # a resume prefill's sample is the request's next
+                        # *global* output token, so it keys on the banked
+                        # count — this is what makes park/resume
+                        # bit-identical (DESIGN.md §17)
+                        steps0[row] = len(r.done_tokens)
+                        completing.append((row, slot, r))
+                copies = self.cache.drain_copies(b)
+                fresh_rows = self.cache.drain_fresh_rows(b * pages)
+                for extra in fresh_rows[1:]:
+                    # more recycled pages than the launch's fresh vector
+                    # carries (long-prompt burst / unaligned chunk
+                    # boundaries): scrub the overflow in dedicated
+                    # fixed-shape calls *before* the launch that writes into
+                    # those pages
+                    if self._scrub is None:
+                        n_fresh = sum(int((fr != 0).sum()) for fr in fresh_rows)
+                        raise ValueError(
+                            f"{n_fresh} fresh pages > pad_to={b * pages} and "
+                            "no scrub_fn installed"
+                        )
+                    self._scrub(extra)
+                restores = self.cache.drain_restores()
+                if restores is not None:
+                    # tier-restored pages (DESIGN.md §18): upload the
+                    # verified payloads into their reserved HBM pages *before*
+                    # the launch that reads through them — the restore is the
+                    # page's full initialization (codes, scales, positions),
+                    # so it needs no scrub and must not race the jitted step
+                    if self._tier_restore is None:
+                        raise ValueError(
+                            f"{len(restores[0])} pending tier restores and no "
+                            "tier_restore_fn installed"
+                        )
+                    rt0 = self._clock()
+                    self._tier_restore(*restores)
+                    rt1 = self._clock()
+                    if self._obs_rooflens is not None:
+                        self._obs_rooflens.observe_tier_restore(
+                            len(restores[0]),
+                            self.cache.bytes_per_token() * bs,
+                            rt1 - rt0,
+                        )
+                    if self._obs_metrics is not None:
+                        self._obs_metrics.histogram(
+                            "serve.tier.restore_wall_s", unit="s"
+                        ).record(rt1 - rt0)
+            if Span.profiling():
+                span.annotate(rows=b, span=sp,
+                              real_tokens=sum(n for _, _, _, n in rows))
+            with Span("serve.prefill.launch", self._span_clock) as launch:
+                logits = self._prefill(
+                    tokens, positions, tables, write_slots, write_pos,
+                    fresh_rows[0], copies, last_idx,
                 )
-            self._scrub(extra)
-        restores = self.cache.drain_restores()
-        if restores is not None:
-            # tier-restored pages (DESIGN.md §18): upload the verified
-            # payloads into their reserved HBM pages *before* the launch
-            # that reads through them — the restore is the page's full
-            # initialization (codes, scales, positions), so it needs no
-            # scrub and must not race the jitted step
-            if self._tier_restore is None:
-                raise ValueError(
-                    f"{len(restores[0])} pending tier restores and no "
-                    "tier_restore_fn installed"
+            with Span("serve.prefill.sync", self._span_clock) as sync:
+                if self._poison_pending and completing:
+                    # chaos "poison_prefill" (DESIGN.md §17): NaN one real
+                    # row's logits before sampling — the guard below must
+                    # fail exactly this request and leave its batch-mates
+                    # untouched
+                    import jax.numpy as jnp
+                    self._poison_pending = False
+                    logits = jnp.asarray(logits).at[completing[0][0]].set(
+                        jnp.nan
+                    )
+                failed_rows: set = set()
+                if self._guard_nonfinite and completing:
+                    import jax.numpy as jnp
+                    finite = np.asarray(
+                        jnp.all(jnp.isfinite(jnp.asarray(logits)), axis=-1)
+                    )
+                    failed_rows = {
+                        row for row, _, _ in completing if not bool(finite[row])
+                    }
+                toks = self._sample(logits, rids, steps0)
+            for row, slot, r in completing:
+                if row in failed_rows:
+                    # ordered before out/prefix_insert: a poisoned request
+                    # never emits a token and never seeds the prefix index
+                    self._fail(slot, r)
+                    continue
+                r.out.append(int(toks[row]))
+                self.cache.prefix_insert(r.rid, r.prompt)
+            for row, (slot, r, start, n) in enumerate(rows):
+                if row in failed_rows:
+                    continue  # released: its pages are already reclaimed
+                r.peak_blocks = max(
+                    r.peak_blocks, self.cache.blocks_held(r.rid)
                 )
-            rt0 = self._clock()
-            self._tier_restore(*restores)
-            rt1 = self._clock()
-            if self._obs_rooflens is not None:
-                self._obs_rooflens.observe_tier_restore(
-                    len(restores[0]),
-                    self.cache.bytes_per_token() * bs,
-                    rt1 - rt0,
-                )
-            if self._obs_metrics is not None:
-                self._obs_metrics.histogram(
-                    "serve.tier.restore_wall_s", unit="s"
-                ).record(rt1 - rt0)
-        observing = (
-            self._obs_tracer is not None or self._obs_rooflens is not None
-            or self._obs_metrics is not None
-        )
-        t0 = self._obs_clock() if observing else 0.0
-        logits = self._prefill(
-            tokens, positions, tables, write_slots, write_pos, fresh_rows[0],
-            copies, last_idx,
-        )
-        if self._poison_pending and completing:
-            # chaos "poison_prefill" (DESIGN.md §17): NaN one real row's
-            # logits before sampling — the guard below must fail exactly
-            # this request and leave its batch-mates untouched
-            import jax.numpy as jnp
-            self._poison_pending = False
-            logits = jnp.asarray(logits).at[completing[0][0]].set(jnp.nan)
-        failed_rows: set = set()
-        if self._guard_nonfinite and completing:
-            import jax.numpy as jnp
-            finite = np.asarray(
-                jnp.all(jnp.isfinite(jnp.asarray(logits)), axis=-1)
-            )
-            failed_rows = {
-                row for row, _, _ in completing if not bool(finite[row])
-            }
-        toks = self._sample(logits, rids, steps0)
-        # `toks` is host-side: the sample call above was the device->host
-        # sync, so t1 - t0 is the full prefill wall time incl. sampling
-        t1 = self._obs_clock() if observing else 0.0
-        for row, slot, r in completing:
-            if row in failed_rows:
-                # ordered before out/prefix_insert: a poisoned request
-                # never emits a token and never seeds the prefix index
-                self._fail(slot, r)
-                continue
-            r.out.append(int(toks[row]))
-            self.cache.prefix_insert(r.rid, r.prompt)
-        for row, (slot, r, start, n) in enumerate(rows):
-            if row in failed_rows:
-                continue  # released: its pages are already reclaimed
-            r.peak_blocks = max(r.peak_blocks, self.cache.blocks_held(r.rid))
 
-        st = self._stats
-        st["prefill_calls"] += 1
-        st["host_syncs"] += 1
-        if bounded:
-            st["prefill_chunk_calls"] += 1
-        st["prefill_token_steps"] += b * sp
-        st["prefill_real_tokens"] += sum(n for _, _, _, n in rows)
-        if self._obs_tracer is not None:
-            # TTFT attribution: a request's first-token timestamp is the
-            # completing chunk's sync — mid-prefill chunks don't emit one
-            self._obs_tracer.on_prefill(
-                t0, t1,
-                [r.rid for row, _, r in completing if row not in failed_rows],
-                b, sp,
-            )
-        if self._obs_rooflens is not None:
+            st = self._stats
+            st["prefill_calls"] += 1
+            st["host_syncs"] += 1
             if bounded:
-                self._obs_rooflens.observe_prefill_chunk(
-                    b, sp, tw * bs, t1 - t0
+                st["prefill_chunk_calls"] += 1
+            st["prefill_token_steps"] += b * sp
+            st["prefill_real_tokens"] += sum(n for _, _, _, n in rows)
+            t0, t1 = launch.t0, sync.t1  # launch through the host's tokens
+            if self._obs_tracer is not None:
+                # TTFT attribution: a request's first-token timestamp is the
+                # completing chunk's sync — mid-prefill chunks don't emit one
+                self._obs_tracer.on_prefill(
+                    t0, t1,
+                    [r.rid for row, _, r in completing
+                     if row not in failed_rows],
+                    b, sp,
                 )
-            else:
-                self._obs_rooflens.observe_prefill(b, sp, t1 - t0)
-        if self._obs_metrics is not None:
-            self._obs_metrics.histogram(
-                "serve.prefill.wall_s", unit="s"
-            ).record(t1 - t0)
-            self._obs_metrics.counter("serve.host_syncs", unit="calls").inc()
+            if self._obs_rooflens is not None:
+                if bounded:
+                    self._obs_rooflens.observe_prefill_chunk(
+                        b, sp, tw * bs, t1 - t0
+                    )
+                else:
+                    self._obs_rooflens.observe_prefill(b, sp, t1 - t0)
 
     # ------------------------------------------------------------------
     # decode: single-step (chunk == 1) or device-resident chunk
@@ -1088,145 +1111,160 @@ class Scheduler:
         assert self.cache.pending_restores == 0, (
             "unflushed tier restores at decode"
         )
-        if self._spec is not None and self._spec_enabled:
-            self._decode_active_spec(active)
-        elif self.chunk > 1:
-            self._decode_active_chunked(active)
-        else:
-            self._decode_active_single(active)
+        with Span("serve.decode") as span:
+            if self._spec is not None and self._spec_enabled:
+                self._decode_active_spec(active)
+            elif self.chunk > 1:
+                self._decode_active_chunked(active, span)
+            else:
+                self._decode_active_single(active, span)
 
-    def _decode_active_single(self, active) -> None:
+    def _decode_active_single(self, active, span: Span) -> None:
         m, mb = self.max_slots, self.max_blocks
-        tokens = np.zeros((m, 1), np.int32)
-        positions = np.zeros((m, 1), np.int32)
-        write_pos = np.full((m, 1), CACHE_EMPTY_POS, np.int32)
-        write_slots = np.zeros((m, 1), np.int32)  # null page, offset 0
-        tables = np.zeros((m, mb), np.int32)
-        kv_lens = np.zeros(m, np.int32)
-        rids = np.full(m, -1, np.int64)  # -1: unreachable uint32 sentinel
-        steps = np.zeros(m, np.int64)
-        for i, r in active:
-            pos = r.next_pos - 1  # feed back the last sampled token
-            tokens[i, 0] = r.out[-1]
-            positions[i, 0] = pos
-            write_pos[i, 0] = pos
-            write_slots[i, 0] = self.cache.write_slots(r.rid, pos, 1)[0]
-            tables[i] = self.cache.block_table_row(r.rid, mb)
-            kv_lens[i] = r.next_pos  # incl. the token this step writes
-            rids[i] = r.rid
-            steps[i] = r.emitted  # global output index: park/resume invariant
-        fresh = self.cache.drain_fresh(m)
-        observing = (
-            self._obs_tracer is not None or self._obs_rooflens is not None
-            or self._obs_metrics is not None
-        )
-        t0 = self._obs_clock() if observing else 0.0
-        logits = self._decode(
-            tokens, positions, tables, write_slots, write_pos, fresh, kv_lens
-        )
-        toks = self._sample(logits, rids, steps)
-        t1 = self._obs_clock() if observing else 0.0
-        for i, r in active:
-            r.out.append(int(toks[i]))
-            r.peak_blocks = max(r.peak_blocks, self.cache.blocks_held(r.rid))
+        with Span("serve.decode.pack"):
+            tokens = np.zeros((m, 1), np.int32)
+            positions = np.zeros((m, 1), np.int32)
+            write_pos = np.full((m, 1), CACHE_EMPTY_POS, np.int32)
+            write_slots = np.zeros((m, 1), np.int32)  # null page, offset 0
+            tables = np.zeros((m, mb), np.int32)
+            kv_lens = np.zeros(m, np.int32)
+            rids = np.full(m, -1, np.int64)  # -1: unreachable uint32 sentinel
+            steps = np.zeros(m, np.int64)
+            for i, r in active:
+                pos = r.next_pos - 1  # feed back the last sampled token
+                tokens[i, 0] = r.out[-1]
+                positions[i, 0] = pos
+                write_pos[i, 0] = pos
+                write_slots[i, 0] = self.cache.write_slots(r.rid, pos, 1)[0]
+                tables[i] = self.cache.block_table_row(r.rid, mb)
+                kv_lens[i] = r.next_pos  # incl. the token this step writes
+                rids[i] = r.rid
+                steps[i] = r.emitted  # global output index: park/resume
+            fresh = self.cache.drain_fresh(m)
+        if Span.profiling():
+            span.annotate(scan_steps=1, slots=len(active),
+                          kv_tokens=int(kv_lens.sum(dtype=np.int64)))
+        with Span("serve.decode.launch", self._span_clock) as launch:
+            logits = self._decode(
+                tokens, positions, tables, write_slots, write_pos, fresh,
+                kv_lens,
+            )
+        with Span("serve.decode.sync", self._span_clock) as sync:
+            toks = self._sample(logits, rids, steps)
+        with Span("serve.decode.replay"):
+            for i, r in active:
+                r.out.append(int(toks[i]))
+                r.peak_blocks = max(
+                    r.peak_blocks, self.cache.blocks_held(r.rid)
+                )
 
-        self._account_decode(1, len(active))
-        self._account_kv_read(int(kv_lens[i]) for i, _ in active)
-        self._observe_decode(
-            t0, t1, 1, {r.rid: 1 for _, r in active},
-            [int(kv_lens[i]) for i, _ in active],
-        )
+            self._account_decode(1, len(active))
+            self._account_kv_read(int(kv_lens[i]) for i, _ in active)
+            self._observe_decode(
+                launch.t0, sync.t1, 1, {r.rid: 1 for _, r in active},
+                [int(kv_lens[i]) for i, _ in active],
+            )
 
-        for i, r in active:
-            if self._finished(r):
-                self._evict(i)
-        self._free_window_pages()
+            for i, r in active:
+                if self._finished(r):
+                    self._evict(i)
+            self._free_window_pages()
 
-    def _decode_active_chunked(self, active) -> None:
+    def _decode_active_chunked(self, active, span: Span) -> None:
         """Precompute a whole chunk's slot/position advancement, run it as
         one device-resident scan, then replay the sampled tokens against
         host request state (EOS / length caps are also computed on device;
         the replay only decides how many of the C tokens each slot keeps)."""
         m, mb, bs = self.max_slots, self.max_blocks, self.cache.block_size
-        rem = {i: r.max_new_tokens - r.emitted for i, r in active}
-        c = min(self.chunk, _pow2ceil(max(rem.values())))
-        f = m * ((c + bs - 1) // bs + 1)  # fresh-page bound for the chunk
+        with Span("serve.decode.pack"):
+            rem = {i: r.max_new_tokens - r.emitted for i, r in active}
+            c = min(self.chunk, _pow2ceil(max(rem.values())))
+            f = m * ((c + bs - 1) // bs + 1)  # fresh-page bound for the chunk
 
-        # snapshot page state before the chunk pre-allocates, so the
-        # accounting below can replay the single-step charging order
-        used0 = self.cache.allocator.used_count
-        held0 = {i: self.cache.blocks_held(r.rid) for i, r in active}
-        p0s: Dict[int, int] = {}
+            # snapshot page state before the chunk pre-allocates, so the
+            # accounting below can replay the single-step charging order
+            used0 = self.cache.allocator.used_count
+            held0 = {i: self.cache.blocks_held(r.rid) for i, r in active}
+            p0s: Dict[int, int] = {}
 
-        tokens0 = np.zeros((m, 1), np.int32)
-        positions = np.zeros((c, m, 1), np.int32)
-        write_slots = np.zeros((c, m, 1), np.int32)
-        write_pos = np.full((c, m, 1), CACHE_EMPTY_POS, np.int32)
-        tables = np.zeros((m, mb), np.int32)
-        kv_lens = np.zeros((c, m), np.int32)
-        rids = np.full(m, -1, np.int64)
-        start_steps = np.zeros(m, np.int64)
-        max_steps = np.zeros(m, np.int32)
-        eos = np.full(m, -1, np.int32)
-        act = np.zeros(m, bool)
-        for i, r in active:
-            p0 = p0s[i] = r.next_pos - 1
-            si = min(c, rem[i])
-            tokens0[i, 0] = r.out[-1]
-            rids[i] = r.rid
-            start_steps[i] = r.emitted  # sampling keys ride the global index
-            max_steps[i] = si
-            act[i] = True
-            if r.eos_id is not None:
-                eos[i] = r.eos_id
-            # pre-allocate the chunk's pages now; the device table is
-            # static for the whole chunk (future slots are scrubbed-empty
-            # and mask to zero attention weight until written)
-            slots_i = self.cache.write_slots(r.rid, p0, si)
-            positions[:, i, 0] = p0 + np.arange(c)
-            write_slots[:si, i, 0] = slots_i
-            write_pos[:si, i, 0] = p0 + np.arange(si)
-            # the §13 length vector: the fused page walk at step j covers
-            # the tokens written through position p0 + j (the chunk's
-            # pre-allocated future pages sit scrubbed-empty past it)
-            kv_lens[:, i] = p0 + 1 + np.arange(c)
-        for i, r in active:
-            tables[i] = self.cache.block_table_row(r.rid, mb)
-        fresh = np.zeros((c, f), np.int32)
-        fresh[0] = self.cache.drain_fresh(f)
+            tokens0 = np.zeros((m, 1), np.int32)
+            positions = np.zeros((c, m, 1), np.int32)
+            write_slots = np.zeros((c, m, 1), np.int32)
+            write_pos = np.full((c, m, 1), CACHE_EMPTY_POS, np.int32)
+            tables = np.zeros((m, mb), np.int32)
+            kv_lens = np.zeros((c, m), np.int32)
+            rids = np.full(m, -1, np.int64)
+            start_steps = np.zeros(m, np.int64)
+            max_steps = np.zeros(m, np.int32)
+            eos = np.full(m, -1, np.int32)
+            act = np.zeros(m, bool)
+            for i, r in active:
+                p0 = p0s[i] = r.next_pos - 1
+                si = min(c, rem[i])
+                tokens0[i, 0] = r.out[-1]
+                rids[i] = r.rid
+                start_steps[i] = r.emitted  # sampling keys: global index
+                max_steps[i] = si
+                act[i] = True
+                if r.eos_id is not None:
+                    eos[i] = r.eos_id
+                # pre-allocate the chunk's pages now; the device table is
+                # static for the whole chunk (future slots are
+                # scrubbed-empty and mask to zero attention weight until
+                # written)
+                slots_i = self.cache.write_slots(r.rid, p0, si)
+                positions[:, i, 0] = p0 + np.arange(c)
+                write_slots[:si, i, 0] = slots_i
+                write_pos[:si, i, 0] = p0 + np.arange(si)
+                # the §13 length vector: the fused page walk at step j
+                # covers the tokens written through position p0 + j (the
+                # chunk's pre-allocated future pages sit scrubbed-empty
+                # past it)
+                kv_lens[:, i] = p0 + 1 + np.arange(c)
+            for i, r in active:
+                tables[i] = self.cache.block_table_row(r.rid, mb)
+            fresh = np.zeros((c, f), np.int32)
+            fresh[0] = self.cache.drain_fresh(f)
+        if Span.profiling():
+            # the scan runs all c steps, dead tail included, and each step's
+            # attention walks every active slot's context
+            span.annotate(scan_steps=c, slots=len(active),
+                          kv_tokens=int(kv_lens.sum(dtype=np.int64)))
+        with Span("serve.decode.launch", self._span_clock) as launch:
+            toks = self._decode_chunk(
+                tokens0, tables, positions, write_slots, write_pos, fresh,
+                kv_lens, rids, start_steps, max_steps, eos, act,
+            )
+        with Span("serve.decode.sync", self._span_clock) as sync:
+            toks = np.asarray(toks)  # (c, m): the chunk's one host sync
 
-        observing = (
-            self._obs_tracer is not None or self._obs_rooflens is not None
-            or self._obs_metrics is not None
-        )
-        t0 = self._obs_clock() if observing else 0.0
-        toks = self._decode_chunk(
-            tokens0, tables, positions, write_slots, write_pos, fresh,
-            kv_lens, rids, start_steps, max_steps, eos, act,
-        )  # (c, m) np.int32 — host-side: the chunk's one device->host sync
-        t1 = self._obs_clock() if observing else 0.0
+        with Span("serve.decode.replay"):
+            steps_taken: Dict[int, int] = {}
+            for i, r in active:
+                for j in range(int(max_steps[i])):
+                    r.out.append(int(toks[j, i]))
+                    if self._finished(r):
+                        break
+                steps_taken[i] = r.emitted - int(start_steps[i])
+                r.peak_blocks = max(
+                    r.peak_blocks, self.cache.blocks_held(r.rid)
+                )
 
-        steps_taken: Dict[int, int] = {}
-        for i, r in active:
-            for j in range(int(max_steps[i])):
-                r.out.append(int(toks[j, i]))
+            self._account_decode_chunk(
+                active, steps_taken, used0, held0, p0s, c
+            )
+            # the fixed-shape scan always runs all c steps, so the roofline
+            # prediction is over c; the tracer gets only the kept tokens
+            self._observe_decode(
+                launch.t0, sync.t1, c,
+                {r.rid: steps_taken[i] for i, r in active},
+                [p0s[i] + 1 for i, _ in active],
+            )
+
+            for i, r in active:
                 if self._finished(r):
-                    break
-            steps_taken[i] = r.emitted - int(start_steps[i])
-            r.peak_blocks = max(r.peak_blocks, self.cache.blocks_held(r.rid))
-
-        self._account_decode_chunk(active, steps_taken, used0, held0, p0s, c)
-        # the fixed-shape scan always runs all c steps, so the roofline
-        # prediction is over c; the tracer gets only the kept tokens
-        self._observe_decode(
-            t0, t1, c, {r.rid: steps_taken[i] for i, r in active},
-            [p0s[i] + 1 for i, _ in active],
-        )
-
-        for i, r in active:
-            if self._finished(r):
-                self._evict(i)
-        self._free_window_pages()
+                    self._evict(i)
+            self._free_window_pages()
 
     def _decode_active_spec(self, active) -> None:
         """Speculative decode round (DESIGN.md §16): `spec_rounds`
@@ -1240,97 +1278,92 @@ class Scheduler:
         m, bs = self.max_slots, self.cache.block_size
         k, rounds = self.spec_k, self.spec_rounds
         cap = rounds * (k + 1)
-        rem = {i: r.max_new_tokens - r.emitted for i, r in active}
+        with Span("serve.decode.pack"):
+            rem = {i: r.max_new_tokens - r.emitted for i, r in active}
 
-        used0 = self.cache.allocator.used_count
-        held0 = {i: self.cache.blocks_held(r.rid) for i, r in active}
-        p0s: Dict[int, int] = {}
-        sis: Dict[int, int] = {}
+            used0 = self.cache.allocator.used_count
+            held0 = {i: self.cache.blocks_held(r.rid) for i, r in active}
+            p0s: Dict[int, int] = {}
+            sis: Dict[int, int] = {}
 
-        tokens0 = np.zeros((m, 1), np.int32)
-        p0 = np.zeros(m, np.int32)
-        rids = np.full(m, -1, np.int64)
-        start_steps = np.zeros(m, np.int64)
-        max_steps = np.zeros(m, np.int32)
-        eos = np.full(m, -1, np.int32)
-        act = np.zeros(m, bool)
-        for i, r in active:
-            pos0 = p0s[i] = r.next_pos - 1
-            si = sis[i] = min(cap, rem[i])
-            tokens0[i, 0] = r.out[-1]
-            p0[i] = pos0
-            rids[i] = r.rid
-            start_steps[i] = r.emitted  # global index, park/resume invariant
-            max_steps[i] = si
-            act[i] = True
-            if r.eos_id is not None:
-                eos[i] = r.eos_id
-            # pre-allocate the full-acceptance span; the device computes
-            # write slots from the table, and rollback below returns
-            # whatever rejection left unwritten
-            self.cache.write_slots(r.rid, pos0, si)
-        # the bounded-table trick (PR 5/PR 7): width covers the furthest
-        # slot's span, pow2-rounded — it serves both the draft's fused walk
-        # and the verify gather, so neither pays max_blocks
-        tw = min(
-            _pow2ceil(max(
-                math.ceil((p0s[i] + sis[i]) / bs) for i, _ in active
-            )),
-            self.max_blocks,
-        )
-        tables = np.zeros((m, tw), np.int32)
-        for i, r in active:
-            tables[i] = self.cache.block_table_row(r.rid, tw)
-        fresh = self.cache.drain_fresh(m * ((cap + bs - 1) // bs + 1))
-
-        observing = (
-            self._obs_tracer is not None or self._obs_rooflens is not None
-            or self._obs_metrics is not None
-        )
-        t0 = self._obs_clock() if observing else 0.0
-        out, e_rounds = self._spec(
-            tokens0, tables, p0, fresh, rids, start_steps, max_steps, eos,
-            act,
-        )  # out (cap, m) packed emissions, e_rounds (rounds, m)
-        t1 = self._obs_clock() if observing else 0.0
-
-        steps_taken: Dict[int, int] = {}
-        for i, r in active:
-            emitted = 0
-            for t in range(rounds):
-                for _ in range(int(e_rounds[t, i])):
-                    r.out.append(int(out[emitted, i]))
-                    emitted += 1
-            steps_taken[i] = emitted
-            r.peak_blocks = max(r.peak_blocks, self.cache.blocks_held(r.rid))
-            # rewind to the committed length: positions >= next_pos - 1
-            # hold only rejected-draft junk (the pending token's KV is
-            # written next round), so their whole pages are dead weight
-            self.cache.rollback(r.rid, r.next_pos - 1)
-
-        self._account_decode_spec(active, e_rounds, p0s, held0, used0, tw)
-        kept = {r.rid: steps_taken[i] for i, r in active}
-        live_rounds = int(np.sum(np.any(np.asarray(e_rounds) > 0, axis=1)))
-        if self._obs_tracer is not None:
-            self._obs_tracer.on_decode_chunk(t0, t1, live_rounds, kept)
-        if self._obs_rooflens is not None:
-            self._obs_rooflens.observe_spec(
-                [p0s[i] + 1 for i, _ in active], k, max(1, live_rounds),
-                t1 - t0,
+            tokens0 = np.zeros((m, 1), np.int32)
+            p0 = np.zeros(m, np.int32)
+            rids = np.full(m, -1, np.int64)
+            start_steps = np.zeros(m, np.int64)
+            max_steps = np.zeros(m, np.int32)
+            eos = np.full(m, -1, np.int32)
+            act = np.zeros(m, bool)
+            for i, r in active:
+                pos0 = p0s[i] = r.next_pos - 1
+                si = sis[i] = min(cap, rem[i])
+                tokens0[i, 0] = r.out[-1]
+                p0[i] = pos0
+                rids[i] = r.rid
+                start_steps[i] = r.emitted  # global index: park/resume
+                max_steps[i] = si
+                act[i] = True
+                if r.eos_id is not None:
+                    eos[i] = r.eos_id
+                # pre-allocate the full-acceptance span; the device computes
+                # write slots from the table, and rollback below returns
+                # whatever rejection left unwritten
+                self.cache.write_slots(r.rid, pos0, si)
+            # the bounded-table trick (PR 5/PR 7): width covers the furthest
+            # slot's span, pow2-rounded — it serves both the draft's fused
+            # walk and the verify gather, so neither pays max_blocks
+            tw = min(
+                _pow2ceil(max(
+                    math.ceil((p0s[i] + sis[i]) / bs) for i, _ in active
+                )),
+                self.max_blocks,
             )
-        if self._obs_metrics is not None:
-            mreg = self._obs_metrics
-            mreg.histogram("serve.decode.chunk_wall_s", unit="s").record(t1 - t0)
-            mreg.counter("serve.host_syncs", unit="calls").inc()
-            mreg.counter("serve.decode.tokens", unit="tokens").inc(
-                sum(kept.values())
-            )
-            self._publish_gauges()
+            tables = np.zeros((m, tw), np.int32)
+            for i, r in active:
+                tables[i] = self.cache.block_table_row(r.rid, tw)
+            fresh = self.cache.drain_fresh(m * ((cap + bs - 1) // bs + 1))
+        with Span("serve.decode.launch", self._span_clock) as launch:
+            out, e_rounds = self._spec(
+                tokens0, tables, p0, fresh, rids, start_steps, max_steps,
+                eos, act,
+            )  # out (cap, m) packed emissions, e_rounds (rounds, m)
+        with Span("serve.decode.sync", self._span_clock) as sync:
+            out, e_rounds = np.asarray(out), np.asarray(e_rounds)
 
-        for i, r in active:
-            if self._finished(r):
-                self._evict(i)
-        self._free_window_pages()
+        with Span("serve.decode.replay"):
+            steps_taken: Dict[int, int] = {}
+            for i, r in active:
+                emitted = 0
+                for t in range(rounds):
+                    for _ in range(int(e_rounds[t, i])):
+                        r.out.append(int(out[emitted, i]))
+                        emitted += 1
+                steps_taken[i] = emitted
+                r.peak_blocks = max(
+                    r.peak_blocks, self.cache.blocks_held(r.rid)
+                )
+                # rewind to the committed length: positions >= next_pos - 1
+                # hold only rejected-draft junk (the pending token's KV is
+                # written next round), so their whole pages are dead weight
+                self.cache.rollback(r.rid, r.next_pos - 1)
+
+            self._account_decode_spec(active, e_rounds, p0s, held0, used0, tw)
+            kept = {r.rid: steps_taken[i] for i, r in active}
+            live_rounds = int(np.sum(np.any(e_rounds > 0, axis=1)))
+            t0, t1 = launch.t0, sync.t1
+            if self._obs_tracer is not None:
+                self._obs_tracer.on_decode_chunk(t0, t1, live_rounds, kept)
+            if self._obs_rooflens is not None:
+                self._obs_rooflens.observe_spec(
+                    [p0s[i] + 1 for i, _ in active], k, max(1, live_rounds),
+                    t1 - t0,
+                )
+            if self._obs_metrics is not None:
+                self._publish_gauges()
+
+            for i, r in active:
+                if self._finished(r):
+                    self._evict(i)
+            self._free_window_pages()
 
     def _account_decode_spec(
         self,
@@ -1486,21 +1519,15 @@ class Scheduler:
     def _observe_decode(self, t0: float, t1: float, steps: int,
                         kept: Dict[int, int], kv_lens: List[int]) -> None:
         """Feed one decode round to whichever collectors are installed
-        (DESIGN.md §14). `steps` is scan steps launched, `kept` the tokens
-        each request keeps, `kv_lens` the active slots' context lengths at
-        round start. No-op (and never called with clock reads) when no
-        collector is installed."""
+        (DESIGN.md §14). `t0`/`t1` are the launch's start and the sync's
+        end, read by the spans only when a collector times them; `steps`
+        is scan steps launched, `kept` the tokens each request keeps,
+        `kv_lens` the active slots' context lengths at round start."""
         if self._obs_tracer is not None:
             self._obs_tracer.on_decode_chunk(t0, t1, steps, kept)
         if self._obs_rooflens is not None:
             self._obs_rooflens.observe_decode(kv_lens, steps, t1 - t0)
         if self._obs_metrics is not None:
-            m = self._obs_metrics
-            m.histogram("serve.decode.chunk_wall_s", unit="s").record(t1 - t0)
-            m.counter("serve.host_syncs", unit="calls").inc()
-            m.counter("serve.decode.tokens", unit="tokens").inc(
-                sum(kept.values())
-            )
             self._publish_gauges()
 
     def _publish_gauges(self) -> None:

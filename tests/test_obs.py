@@ -20,7 +20,7 @@ from repro.configs.base import get_smoke_config
 from repro.models.model import Model
 from repro.obs import MetricsRegistry, Observability, RoofLens, Tracer
 from repro.obs.metrics import Histogram, exact_percentiles
-from repro.serve.engine import GenerationEngine
+from repro.serve.engine import GenerationEngine, SpecConfig
 from repro.serve.scheduler import STAT_UNITS
 
 
@@ -373,7 +373,7 @@ def test_stats_fold_into_registry_and_pool_gauges(llama):
         + snap["serve.pool.free_pages"]["value"]
         == occ["total"]
     )
-    assert snap["serve.host_syncs"]["value"] == (
+    assert snap["serve.stats.host_syncs"]["value"] == (
         eng.scheduler.stats()["host_syncs"]
     )
 
@@ -458,3 +458,178 @@ def test_rooflens_engine_loose_factor(llama):
     assert obs.metrics.histogram(
         "rooflens.decode.measured_s", unit="s"
     ).count >= dec["n"]
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, and kernel scopes in the HLO
+# ---------------------------------------------------------------------------
+
+SPAN_PARENTS = {
+    "serve.admit": "serve.step", "serve.prefill": "serve.step",
+    "serve.decode": "serve.step",
+    **{f"serve.prefill.{p}": "serve.prefill"
+       for p in ("pack", "launch", "sync")},
+    **{f"serve.decode.{p}": "serve.decode"
+       for p in ("pack", "launch", "sync", "replay")},
+}
+
+
+def _profiled_drain(m, params, prompts, n_steps, chunk, tmp_path, **kw):
+    """Drain `prompts` under the profiler; the program's `serve.*` host
+    events (name, start, end, int args) and the stats deltas."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    eng = GenerationEngine(
+        m, params, max_len=64, block_size=8, max_slots=len(prompts),
+        decode_chunk=chunk, **kw,
+    )
+    st0 = eng.scheduler.stats()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        for p in prompts:
+            eng.submit(p, max_new_tokens=n_steps)
+        eng.run_until_drained()
+    st1 = eng.scheduler.stats()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    events = [
+        (ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:CPU")
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("serve.")
+    ]
+    return events, {k: st1[k] - st0[k] for k in st0}
+
+
+@pytest.mark.parametrize("chunk", [1, 4])
+def test_serving_spans_nest_and_count_the_launched_work(llama, chunk, tmp_path):
+    """The scheduler's spans nest as named, and their arguments count the
+    device's work: two requests admitted together run 8 decode steps in
+    lockstep (no dead chunk tail), each step's attention reading every
+    slot's context through the token it writes."""
+    m, params = llama
+    lengths, n_steps = (5, 13), 9
+    prompts = _prompts(m.cfg.vocab_size, lengths)
+    events, delta = _profiled_drain(m, params, prompts, n_steps, chunk,
+                                    tmp_path)
+    names = [e[0] for e in events]
+    assert names.count("serve.submit") == len(prompts)
+    for name, s, e, _ in events:
+        parent = SPAN_PARENTS.get(name)
+        if parent is not None:
+            assert any(n == parent and ps <= s and e <= pe
+                       for n, ps, pe, _ in events), name
+    decode = [a for n, _, _, a in events if n == "serve.decode"]
+    prefill = [a for n, _, _, a in events if n == "serve.prefill"]
+    assert len(decode) == delta["decode_chunks"]
+    assert len(prefill) == delta["prefill_calls"] == 1
+    steps = n_steps - 1  # the first token comes from the prefill
+    assert sum(a["scan_steps"] for a in decode) == steps == delta["decode_steps"]
+    assert all(a["slots"] == len(prompts) for a in decode)
+    # decoding output j + 1 reads the prompt and j outputs
+    assert sum(a["kv_tokens"] for a in decode) == sum(
+        p + j for p in lengths for j in range(1, steps + 1)
+    )
+    (a,) = prefill
+    assert a["rows"] == 2 and a["span"] == 16  # pow2 rows, page-rounded span
+    assert a["real_tokens"] == sum(lengths) == delta["prefill_real_tokens"]
+    assert a["rows"] * a["span"] == delta["prefill_token_steps"]
+
+
+def test_spec_decode_spans_nest_and_state_no_work(llama, tmp_path):
+    """The speculative path opens the same decode spans, and states no
+    work: its draft and verify steps read two weight trees, which one
+    `scan_steps` count cannot price."""
+    m, params = llama
+    prompts = _prompts(m.cfg.vocab_size, (5, 13))
+    events, delta = _profiled_drain(
+        m, params, prompts, 9, 4, tmp_path,
+        spec_decode=SpecConfig(k=2, draft_codec="bf8"),
+    )
+    decode = [(s, e, a) for n, s, e, a in events if n == "serve.decode"]
+    assert decode and len(decode) == delta["decode_chunks"]
+    assert all(a == {} for _, _, a in decode)
+    for child in ("pack", "launch", "sync", "replay"):
+        kids = [(s, e) for n, s, e, _ in events
+                if n == f"serve.decode.{child}"]
+        assert len(kids) == len(decode), child
+        assert all(any(ps <= s and e <= pe for ps, pe, _ in decode)
+                   for s, e in kids), child
+
+
+def test_spans_take_no_clock_and_no_args_when_nothing_listens(llama):
+    """Without a profile or a collector the spans read no clock and the
+    scheduler computes no span arguments."""
+    from repro.obs.trace import Span
+
+    assert not Span.profiling()
+    m, params = llama
+    eng = GenerationEngine(m, params, max_len=64, block_size=8, max_slots=2)
+    assert eng.scheduler._span_clock is None
+    with Span("serve.test") as s:
+        pass
+    assert (s.t0, s.t1) == (0.0, 0.0)
+
+
+def _compressed(llama):
+    from repro.core.decompress import compress_tree
+    from repro.core.formats import get_spec
+
+    m, params = llama
+    return m, compress_tree(params, get_spec("mxfp4_100"))
+
+
+def test_step_programs_carry_the_kernel_scopes(llama):
+    """The decode chunk's and the paged prefill's HLO name every kernel
+    scope the device-trace reduction reads."""
+    m, params = _compressed(llama)
+    eng = GenerationEngine(m, params, max_len=64, block_size=8, max_slots=2,
+                           decode_chunk=4)
+    C, M, MB = 4, 2, eng.max_blocks
+    F = M * ((C + 7) // 8 + 1)
+    i32 = np.int32
+    chunk = eng._paged_decode_chunk.lower(
+        eng.params, eng.kv.pools,
+        np.zeros((M, 1), i32), np.zeros((M, MB), i32),
+        np.zeros((C, M, 1), i32), np.zeros((C, M, 1), i32),
+        np.zeros((C, M, 1), i32), np.zeros((C, F), i32),
+        np.ones((C, M), i32),
+        np.zeros(M, np.uint32), np.zeros(M, np.uint32),
+        np.full(M, C, i32), np.full(M, -1, i32), np.ones(M, bool),
+        np.float32(1.0), jax.random.PRNGKey(0), greedy=True,
+    ).as_text(debug_info=True)
+    for scope in ("deca.gemv", "deca.paged_attention", "model.head",
+                  "kv.write"):
+        assert scope in chunk, scope
+    assert "deca.gemm" not in chunk
+    B, S = 2, 32  # 64 rows: the GEMM regime
+    prefill = eng._paged_prefill.lower(
+        eng.params, np.zeros((B, S), i32), np.zeros((B, S), i32),
+        eng.kv.pools, np.zeros((B, MB), i32), np.zeros((B, S), i32),
+        np.zeros((B, S), i32), np.zeros(B * 4, i32), np.zeros((B, 2), i32),
+        np.zeros(B, i32),
+    ).as_text(debug_info=True)
+    for scope in ("deca.gemm", "model.attention", "model.head", "kv.write"):
+        assert scope in prefill, scope
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+@pytest.mark.parametrize("rows,scope", [(4, "deca.gemv"), (64, "deca.gemm")])
+def test_decompress_gemm_scope_names_the_kernel_not_the_impl(impl, rows,
+                                                             scope):
+    import jax.numpy as jnp
+
+    from repro.core.compression import compress
+    from repro.core.formats import get_spec
+    from repro.kernels import ops
+
+    w = np.random.default_rng(0).standard_normal((256, 256)).astype(np.float32)
+    ct = compress(w, get_spec("bf8_50"))
+    text = jax.jit(
+        lambda x: ops.decompress_gemm(x, ct, impl=impl)
+    ).lower(jnp.ones((rows, 256), jnp.bfloat16)).as_text(debug_info=True)
+    other = "deca.gemm" if scope == "deca.gemv" else "deca.gemv"
+    assert scope in text and other not in text
