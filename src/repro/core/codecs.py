@@ -259,6 +259,27 @@ class Codec:
         """(ng, packed_k, N) stored codes -> (ng, k_cap, N) f32 values."""
         raise NotImplementedError
 
+    @property
+    def n_planes(self) -> int:
+        """Value planes a stored code row holds: 2 for nibble-packed codes
+        (slot 2j in the low nibble of byte j, 2j+1 in the high), else 1."""
+        return 2 if self.bits == 4 else 1
+
+    def decode_planes(self, codes: jax.Array) -> Tuple[jax.Array, ...]:
+        """Stored codes -> `n_planes` f32 value planes in stored layout:
+        plane p holds slots p, p + n_planes, ... of each group, one per
+        stored code row, so nibble codes decode without the sublane
+        interleave of `decode_values` (the fused decode GEMV contracts
+        each plane with its own columns of x)."""
+        if self.n_planes == 1:
+            return (self.decode_values(codes),)
+        c = _widen(codes)
+        return self.decode_nibbles(c & 0xF), self.decode_nibbles(c >> 4)
+
+    def decode_nibbles(self, nib: jax.Array) -> jax.Array:
+        """int32 nibble codes -> f32 values (4-bit codecs)."""
+        raise NotImplementedError
+
     def decode_scales(self, scales: jax.Array) -> jax.Array:
         """(ng, N) stored scales -> (ng, N) f32 multipliers, built from
         bits (exact; no `exp2`, no 8- or 16-bit arithmetic)."""
@@ -351,21 +372,17 @@ def _bf8_decode_jnp(codes: jax.Array) -> jax.Array:
     return _f32_from_bits(mag | ((c >> 7) << 31))
 
 
-def _fp4_mag_jnp(nib: jax.Array) -> jax.Array:
-    """E2M1 nibble (sign stripped) -> magnitude, pure ALU (no LUT).
-
-    value = m/2              if e == 0   (subnormal)
-          = (1 + m/2)*2^(e-1) otherwise   (f32 exponent field e + 126)
-    """
-    e = (nib >> 1) & 0x3
-    m = nib & 0x1
-    normal = _f32_from_bits(((e + 126) << 23) | (m << 22))
-    return jnp.where(e == 0, 0.5 * m.astype(jnp.float32), normal)
-
-
 def _fp4_decode_jnp(nib: jax.Array) -> jax.Array:
-    mag = _fp4_mag_jnp(nib)
-    return jnp.where((nib >> 3) == 1, -mag, mag)
+    """E2M1 nibble -> f32, built from bits, pure ALU (no LUT).
+
+    The magnitude code c = nib & 7 is (e, m) = (c >> 1, c & 1). For c >= 2
+    the value (1 + m/2) * 2^(e-1) is the f32 with exponent field e + 126
+    and mantissa bit m, i.e. bits (c + 252) << 22; c == 1 is 0.5
+    (0x3F000000), c == 0 is zero; bit 3 is the sign bit."""
+    c = nib & 7
+    sub = jnp.where(c == 1, 0x3F000000, 0)
+    mag = jnp.where(c >= 2, (c + 252) << 22, sub)
+    return _f32_from_bits(mag | ((nib & 8) << 28))
 
 
 class MXFP4Codec(Codec):
@@ -393,7 +410,10 @@ class MXFP4Codec(Codec):
         return vals * (2.0 ** (scales.astype(np.float32) - 127.0))[:, None, :]
 
     def decode_values(self, codes):
-        return _fp4_decode_jnp(_unpack_nibbles_jnp(codes, axis=1))
+        return self.decode_nibbles(_unpack_nibbles_jnp(codes, axis=1))
+
+    def decode_nibbles(self, nib):
+        return _fp4_decode_jnp(nib)
 
     def kv_encode(self, x):
         scale, safe = self._kv_scale(x, 6.0)  # E2M1 max magnitude
@@ -445,11 +465,13 @@ class IntCodec(Codec):
         if self.bits == 8:
             c = _widen(codes)
             return (c - 256 * (c >= 128)).astype(jnp.float32)
-        nib = _unpack_nibbles_jnp(codes, axis=axis)
-        return (nib - 16 * (nib >= 8)).astype(jnp.float32)
+        return self.decode_nibbles(_unpack_nibbles_jnp(codes, axis=axis))
 
     def decode_values(self, codes):
         return self._values(codes, axis=1)
+
+    def decode_nibbles(self, nib):
+        return (nib - 16 * (nib >= 8)).astype(jnp.float32)
 
     def kv_encode(self, x):
         scale, safe = self._kv_scale(x, float(self.qmax))
@@ -496,7 +518,9 @@ class NF4Codec(Codec):
         return NF4_LUT[nib] * _bf16_bits_to_f32(scales)[:, None, :]
 
     def decode_values(self, codes):
-        nib = _unpack_nibbles_jnp(codes, axis=1)
+        return self.decode_nibbles(_unpack_nibbles_jnp(codes, axis=1))
+
+    def decode_nibbles(self, nib):
         return _lut_decode_jnp(nib, NF4_LUT)
 
     def kv_encode(self, x):

@@ -24,7 +24,10 @@ from repro.core.compression import CompressedTensor, compress
 from repro.core.formats import CompressionSpec
 from repro.kernels import ops
 
-_IMPL = "ref"  # 'ref' (portable XLA) | 'pallas' (TPU kernel; interpret on CPU)
+# 'auto' (ops.decompress_gemm picks from the backend and M; attention runs
+# the ref walk) | 'ref' (portable XLA) | 'pallas' (TPU kernels; interpreted
+# on CPU)
+_IMPL = "auto"
 
 
 @contextlib.contextmanager
@@ -38,9 +41,10 @@ def use_impl(impl: str):
 
 
 def current_impl() -> str:
-    """The active kernel impl ('ref' | 'pallas') — also consulted by the
-    fused paged-attention routing in models/layers.py, so `use_impl`
-    switches every DECA kernel on the serving path at once."""
+    """The active kernel impl ('auto' | 'ref' | 'pallas') — also
+    consulted by the fused paged-attention routing in models/layers.py
+    (which runs 'auto' as 'ref'), so `use_impl` switches every DECA kernel
+    on the serving path at once."""
     return _IMPL
 
 

@@ -153,6 +153,75 @@ def pick_blocks(
     return bm, bn, bk
 
 
+# decode-GEMV geometry (`pick_gemv_blocks`)
+GEMV_STEP_BYTES = 2 << 20   # compressed plane bytes one grid step streams
+GEMV_SUB_K = 256            # dense K rows decoded per sub-block
+GEMV_TILE_BYTES = 128 << 10  # f32 bytes of one decoded (sub_k, sub_n) tile
+GEMV_TARGET_N = 1024        # block_n target: output lanes per grid column
+
+
+def gemv_sub_groups(spec: CompressionSpec, ng: int) -> int:
+    """Groups per decode sub-block of the fused GEMV: the fewest whole
+    groups covering `GEMV_SUB_K` dense rows whose code rows (`ck` bytes a
+    group) start on the 32-row uint8 sublane tile and whose mask and
+    decoded scale rows start on the 8-row 32-bit tile. A K with no such
+    split decodes as one sub-block."""
+    ck = math.ceil(spec.k_cap * spec.bits / 8)
+    sg = math.lcm(
+        SUBLANES, 32 // math.gcd(ck, 32), max(1, GEMV_SUB_K // spec.group)
+    )
+    return sg if ng % sg == 0 else ng
+
+
+def gemv_vmem_bytes(
+    m: int, bn: int, bk: int, sg: int, sub_n: int, spec: CompressionSpec
+) -> int:
+    """VMEM working set of one fused-GEMV grid step: double-buffered plane
+    blocks and x columns, the f32 scales decoded once a step, the f32
+    accumulator and output block, and about eight f32 decode temporaries
+    of one (sub-block, sub_n) tile."""
+    planes = spec.bytes_for(bk, bn)
+    x = max(m, 16) * bk * 2
+    scales = (bk // spec.group) * bn * 4 if spec.has_scale else 0
+    acc = 3 * max(m, SUBLANES) * bn * 4
+    temps = 8 * sg * spec.group * sub_n * 4
+    return 2 * (planes + x) + scales + acc + temps
+
+
+def pick_gemv_blocks(
+    m: int,
+    n: int,
+    k: int,
+    spec: CompressionSpec,
+) -> Tuple[int, int, int, int]:
+    """(block_n, block_k, sub_groups, sub_n) of the fused decode GEMV.
+
+    The GEMV streams each plane byte once and decodes it on the VPU, so a
+    grid step has to move enough bytes to hide the step's fixed cost
+    (a few tenths of a microsecond against 1.3 ns a KB at the HBM roof):
+    block_k grows, in whole sub-blocks, until a step moves
+    `GEMV_STEP_BYTES`.
+    Inside a step the decode runs over sub-blocks of `sub_groups` groups
+    and `sub_n` lanes, so a decoded f32 tile is `GEMV_TILE_BYTES` (32
+    vregs): on the v5e wider tiles ran the bf8_50 expansion up to 1.65x
+    slower and the mxfp4 decode no faster (PERF.md §6). One
+    algorithm for every shape; only these numbers differ."""
+    g = spec.group
+    ng = k // g
+    sg = gemv_sub_groups(spec, ng)
+    bn = lane_block(n, GEMV_TARGET_N)
+    per_sub = spec.bytes_for(sg * g, bn)
+    nsub = ng // sg
+    fit = [d for d in divisors(nsub) if d == 1 or d * per_sub <= GEMV_STEP_BYTES]
+    bk = fit[-1] * sg * g
+    if bn % LANES:
+        sub_n = bn
+    else:
+        tile_n = max(LANES, GEMV_TILE_BYTES // (4 * sg * g))
+        sub_n = select_block(bn, tile_n, multiple=LANES, name="sub_n")
+    return bn, bk, sg, sub_n
+
+
 def lane_block(n: int, target: int) -> int:
     """block_n for an output dim `n`: the largest lane-aligned divisor <=
     `target`. A dim wider than a lane row with no such divisor (a 50272

@@ -69,22 +69,55 @@ def decompress_block(
     ng, _, n = vals.shape
     if mask is None:
         return vals.reshape(ng * spec.group, n)
-    # POPCNT analog: element i of a group is the slot-th kept value, where
-    # slot = popcount(mask & (2^i - 1)); unkept elements get slot k_cap
-    m = mask.astype(jnp.uint32)[:, None, :]  # (ng, 1, n)
-    i = jax.lax.broadcasted_iota(jnp.uint32, (1, spec.group, 1), 1)
-    kept = ((m >> i) & 1) == 1
-    slot = jnp.where(
-        kept,
-        jax.lax.population_count(m & ((jnp.uint32(1) << i) - 1)),
-        spec.k_cap,
-    ).astype(jnp.int32)  # (ng, G, n)
+    slot = slots(mask, spec)
     # crossbar analog: a select per packed slot (a per-column gather along
     # sublanes, which Mosaic cannot lower)
     dense = jnp.zeros((ng, spec.group, n), vals.dtype)
     for j in range(spec.k_cap):
         dense = jnp.where(slot == j, vals[:, j : j + 1, :], dense)
     return dense.reshape(ng * spec.group, n)
+
+
+def slots(mask: jax.Array, spec: CompressionSpec) -> jax.Array:
+    """POPCNT analog: (ng, n) bitmask -> (ng, G, n) int32 slot of each
+    element. Element i of a group is the slot-th kept value, where slot =
+    popcount(mask & (2^i - 1)); unkept elements get slot k_cap."""
+    m = mask.astype(jnp.uint32)[:, None, :]  # (ng, 1, n)
+    i = jax.lax.broadcasted_iota(jnp.uint32, (1, spec.group, 1), 1)
+    kept = ((m >> i) & 1) == 1
+    return jnp.where(
+        kept,
+        jax.lax.population_count(m & ((jnp.uint32(1) << i) - 1)),
+        spec.k_cap,
+    ).astype(jnp.int32)
+
+
+def expand_slots(planes, mask: jax.Array, spec: CompressionSpec) -> jax.Array:
+    """The fused GEMV's bitmask expansion: packed slot values -> (ng, G, n)
+    dense groups.
+
+    `planes` holds the slots in P interleaved planes, slot s at row s // P
+    of plane s % P (P = 1: `decode_values` order; P = 2: a nibble codec's
+    `decode_planes`), so nibble codes expand without being interleaved.
+    A group keeps exactly k_cap elements (core/formats.py), so element i
+    holds a slot in [i - (G - k_cap), i]: each 8-row block selects among
+    those slots only, a quarter fewer selects than `decompress_block`'s
+    loop at 50% density."""
+    slot = slots(mask, spec)
+    ng, n = mask.shape
+    p = len(planes)
+    g, kc = spec.group, spec.k_cap
+    blocks = []
+    for r0 in range(0, g, 8):
+        rows = slice(r0, min(r0 + 8, g))
+        block = jnp.zeros((ng, rows.stop - r0, n), planes[0].dtype)
+        for j in range(max(0, r0 - (g - kc)), min(rows.stop, kc)):
+            r = j // p
+            block = jnp.where(
+                slot[:, rows] == j, planes[j % p][:, r : r + 1, :], block
+            )
+        blocks.append(block)
+    return jnp.concatenate(blocks, axis=1)
 
 
 # ---------------------------------------------------------------------------

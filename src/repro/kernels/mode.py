@@ -6,6 +6,9 @@ an explicit `interpret=True/False` wins; otherwise `REPRO_PALLAS_INTERPRET`
 interpreter; `0` compiles for Mosaic anywhere); otherwise the backend
 decides: compiled on a TPU, interpreted everywhere else. So nothing
 interprets on a TPU unless it is told to.
+
+`on_tpu` is that backend test; `kernels/ops.py` routes the decode GEMV
+by it too.
 """
 from __future__ import annotations
 
@@ -29,4 +32,8 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
         raise ValueError(
             f"{INTERPRET_ENV}={env!r}: expected 1/true/yes/on or 0/false/no/off"
         )
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"

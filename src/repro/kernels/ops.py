@@ -11,8 +11,12 @@ Regime split (DESIGN.md §12): below `GEMV_MAX_M` rows the matmul is the
 decode GeMV regime — bandwidth-bound on the weight stream — and both impls
 route to the decode-shaped variants (`ref.decompress_gemv` /
 `decompress_gemv_pallas`) that never materialize the dense (K, N) weight.
-The N-tiled GeMV is bit-identical to the full-matrix path, so routing is a
-pure performance decision and golden-battery equivalence is unaffected.
+With no implementation asked for (`impl="auto"`, what the serving path
+passes by default), a GeMV on a TPU runs the fused Pallas kernel and
+everything else runs the jnp oracle: the choice follows the backend and
+M only. The fused kernel sums over K in blocks, so it matches the oracle
+to f32 rounding, not bit for bit (tests/test_decode_chunk.py argues the
+bound); an explicit `impl="ref"` keeps the oracle on any backend.
 
 `paged_attention` (DESIGN.md §13) is the same split on the decode
 *attention* path: both impls walk the quantized KV pool page by page,
@@ -43,8 +47,9 @@ from repro.core.compression import CompressedTensor
 from repro.kernels import ref
 from repro.kernels.deca_decompress import decompress_pallas
 from repro.kernels.deca_gemm import decompress_gemm_pallas, decompress_gemv_pallas
-from repro.kernels.mode import resolve_interpret
+from repro.kernels.mode import on_tpu, resolve_interpret
 from repro.kernels.paged_attention import paged_attention_pallas
+from repro.obs.metrics import TRACE_COUNTS
 
 # Rows at or below which the decode-shaped GeMV path is used. The decode
 # step's M is the continuous-batching slot count (<= ~32); prefill and
@@ -85,15 +90,18 @@ def decompress_gemm(
     x: jax.Array,
     ct: CompressedTensor,
     *,
-    impl: str = "ref",
+    impl: str = "auto",
     out_dtype=jnp.float32,
     **block_kwargs,
 ) -> jax.Array:
     """Fused-semantics compressed GeMM: x (..., K) @ W (K, N).
 
-    Leading dims of x are flattened to M. impl: 'ref' | 'pallas' | 'gemv'
-    (explicit decode-shaped path; 'ref'/'pallas' auto-route to it when
-    M <= GEMV_MAX_M).
+    Leading dims of x are flattened to M. impl: 'auto' (the fused Pallas
+    GeMV on a TPU at M <= GEMV_MAX_M, the jnp oracle otherwise) | 'ref' |
+    'pallas' | 'gemv' (explicit decode-shaped oracle; 'ref'/'pallas'
+    route to their GeMV when M <= GEMV_MAX_M). Each GeMV call site counts
+    once per trace in `TRACE_COUNTS` under `kernels.gemv_sites.fused` or
+    `.ref`.
     """
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
@@ -106,9 +114,14 @@ def decompress_gemm(
     gemv_pl_kw = {
         k: v for k, v in block_kwargs.items() if k in ("block_n", "block_k")
     }
-    if impl not in ("gemv", "ref", "pallas"):
+    if impl not in ("auto", "gemv", "ref", "pallas"):
         raise ValueError(impl)
     gemv = impl == "gemv" or m <= GEMV_MAX_M
+    if impl == "auto":
+        impl = "pallas" if gemv and on_tpu() else "ref"
+    if gemv:
+        route = "fused" if impl == "pallas" else "ref"
+        TRACE_COUNTS.counter(f"kernels.gemv_sites.{route}", "sites").inc()
     with jax.named_scope(SCOPE_GEMV if gemv else SCOPE_GEMM):
         if impl == "pallas" and gemv:
             out = decompress_gemv_pallas(
@@ -144,9 +157,10 @@ def paged_attention(
     """Fused paged-attention decode (DESIGN.md §13): dequantize-on-read
     inside the page walk, online softmax, length-bounded by `kv_lens` —
     the gathered dense KV view is never materialized. impl: 'ref' (the
-    length-bounded while-loop oracle the model graphs run) | 'pallas'."""
+    length-bounded while-loop oracle the model graphs run; 'auto' is
+    'ref') | 'pallas'."""
     with jax.named_scope(SCOPE_PAGED_ATTENTION):
-        if impl == "ref":
+        if impl in ("ref", "auto"):
             return ref.paged_decode_attention(
                 q, pools, block_tables, kv_lens, q_pos,
                 quant=quant, causal=causal, window=window, softcap=softcap,

@@ -104,9 +104,11 @@ def decompress_gemv(
     CPU, swamping the tile work itself; many tiles fall back to the scan,
     which keeps exactly one tile live regardless of N.
 
-    Tiling over N (not K) keeps each output element a single full-K dot —
-    the result is *bit-identical* to `decompress_gemm`, which K-split
-    accumulation would not be (f32 addition is not associative)."""
+    Tiling over N (not K) keeps each output element a single full-K dot,
+    so the result matches `decompress_gemm` up to the compiler's reduction
+    order, which K-split accumulation would not (f32 addition is not
+    associative). The fused Pallas GEMV the TPU runs splits K, and is held
+    to the f32 summation-order bound instead (DESIGN.md §12)."""
     spec = ct.spec
     K, N = ct.shape
     if x.shape[1] != K:

@@ -202,6 +202,13 @@ class MetricsRegistry:
         return out
 
 
+# Process-wide counts taken while jitted functions are traced, never while
+# they run, where no engine's Observability is in reach:
+# `kernels.gemv_sites.fused` / `.ref` (kernels/ops.py) count the decode
+# GEMV call sites the tracer lowered to each implementation.
+TRACE_COUNTS = MetricsRegistry()
+
+
 def exact_percentiles(samples: List[float],
                       qs=(0.50, 0.90, 0.99)) -> Dict[str, float]:
     """Exact nearest-rank percentiles over a finite sample list (offline

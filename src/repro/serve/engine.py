@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.decompress import current_impl, use_impl
 from repro.dist import sharding as sh
 from repro.models.model import Model
 from repro.obs.trace import Span
@@ -246,8 +247,9 @@ class GenerationEngine:
     DECA CompressedTensor weights, whose codes/mask/scales shard along the
     dense (K, N) axes — with `dist.sharding.param_spec_tree`, lays the KV
     pool out with the §10 rule (pages replicated over 'data', KV heads over
-    'model'), and traces prefill/decode under `use_mesh(mode="serve")`.
-    With `mesh=None` nothing changes.
+    'model'), and traces prefill/decode under `use_mesh(mode="serve")`,
+    on the jnp kernels unless `use_impl` asked for others (GSPMD cannot
+    partition the fused Pallas GEMV). With `mesh=None` nothing changes.
 
     `paged="auto"` (default) uses the paged path for attention stacks and
     falls back to the dense ring cache for ssm/rec stacks; `paged=False`
@@ -488,10 +490,21 @@ class GenerationEngine:
                 ),
             )
 
+    @contextlib.contextmanager
     def _mesh_scope(self):
+        """Trace and run under the engine's mesh. The placed weights are
+        GSPMD-sharded and GSPMD cannot partition a Pallas call, so where
+        no kernel implementation was asked for the steps trace the jnp
+        kernels (`ops.decompress_gemm`'s 'auto' would pick the fused GEMV
+        on a TPU)."""
         if self.mesh is None:
-            return contextlib.nullcontext()
-        return sh.use_mesh(self.mesh, fsdp=self.fsdp, mode="serve")
+            yield
+            return
+        impl = current_impl()
+        with sh.use_mesh(self.mesh, fsdp=self.fsdp, mode="serve"), use_impl(
+            "ref" if impl == "auto" else impl
+        ):
+            yield
 
     def _bind_rooflens(self, lens, max_slots: int) -> None:
         """Bind the RoofLens predicted-vs-measured model (DESIGN.md §14) to

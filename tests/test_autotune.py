@@ -9,6 +9,7 @@ import pytest
 from repro.core.formats import get_spec
 from repro.kernels.autotune import (
     LANES,
+    VMEM_BUDGET,
     kv_page_bytes,
     pick_blocks,
     pick_page_block,
@@ -141,3 +142,40 @@ def test_kv_page_bytes_is_codec_driven():
     assert bf8 < none and int4 < bf8
     # bf8 halves the bf16 payload (position plane aside)
     assert abs(bf8 / none - 0.5) < 0.05
+
+
+# ---------------------------------------------------------------------------
+# pick_gemv_blocks: the fused decode GEMV at the benchmark cells' shapes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("fmt,k,n", [
+    ("mxfp4_100", 9216, 9216), ("mxfp4_100", 9216, 36864),
+    ("mxfp4_100", 36864, 9216), ("mxfp4_100", 9216, 50272),
+    ("bf8_50", 4096, 4096), ("bf8_50", 4096, 1024), ("bf8_50", 4096, 12800),
+    ("bf8_50", 12800, 4096),
+])
+def test_pick_gemv_blocks_cell_shapes(fmt, k, n):
+    """Each grid step streams hundreds of KB to a few MB of planes (a
+    step's fixed cost is tenths of a microsecond; 100 KB is 0.12 us at the
+    HBM roof), K splits into whole blocks of whole sub-blocks of at most
+    GEMV_SUB_K rows, and the working set fits half of VMEM."""
+    from repro.kernels.autotune import (
+        GEMV_STEP_BYTES, GEMV_SUB_K, gemv_vmem_bytes, pick_gemv_blocks,
+    )
+
+    spec = get_spec(fmt)
+    bn, bk, sg, sub_n = pick_gemv_blocks(4, n, k, spec)
+    step = spec.bytes_for(bk, bn)
+    assert 512 * 1024 <= step <= GEMV_STEP_BYTES
+    assert k % bk == 0 and bk % (sg * spec.group) == 0
+    assert sg * spec.group == GEMV_SUB_K
+    assert bn % LANES == 0 and bn % sub_n == 0 and sub_n % LANES == 0
+    assert gemv_vmem_bytes(4, bn, bk, sg, sub_n, spec) <= VMEM_BUDGET
+
+
+def test_pick_gemv_blocks_small_k_is_one_sub_block():
+    """A K with no 8-group split (the tests' widths) decodes whole."""
+    from repro.kernels.autotune import pick_gemv_blocks
+
+    bn, bk, sg, sub_n = pick_gemv_blocks(4, 96, 128, get_spec("nf4_100"))
+    assert (bn, bk, sg, sub_n) == (96, 128, 4, 96)

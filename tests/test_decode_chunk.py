@@ -5,8 +5,9 @@ Chunked multi-step decode (up to `decode_chunk` steps inside one jitted
 scheduler token-for-token: all codecs, EOS mid-chunk, admission mid-drain,
 temperature sampling, and under a 2x1 mesh. Plus the decode-GeMV regime
 checks: the decode step's jaxpr must never materialize a dense f32 (K, N)
-weight for compressed params, and the GeMV path must be bit-identical to
-the full-matrix reference."""
+weight for compressed params, the oracle's GeMV path must be bit-identical
+to the full-matrix reference, the fused Pallas GEMV must match it within
+the f32 summation-order bound, and the route must follow backend and M."""
 import numpy as np
 import pytest
 import jax
@@ -281,15 +282,130 @@ def test_gemv_bit_identical_to_reference(fmt, m_rows):
     np.testing.assert_array_equal(via_ops, want)
 
 
-def test_gemv_pallas_grid_variant_matches_oracle():
+# (K, N, geometry): the first is the shape this check began with, at the
+# default geometry (one block, one sub-block); the second spans two K
+# blocks of two sub-blocks each and a ragged last N block (200 = 128 + 72)
+GEMV_GRIDS = {
+    "K256-N96": (256, 96, {}),
+    "K1024-N200": (1024, 200, dict(block_n=128, block_k=512)),
+}
+
+
+@pytest.mark.parametrize("grid", list(GEMV_GRIDS))
+@pytest.mark.parametrize("m_rows", [1, 4, 8, 32])
+@pytest.mark.parametrize("fmt", ["mxfp4_100", "bf8_50", "int4_25", "nf4_100"])
+def test_gemv_pallas_grid_variant_matches_oracle(fmt, m_rows, grid):
+    """The fused GEMV (interpreted here) against the full-matrix oracle.
+
+    Both compute sum_k x[m, k] * w[k, n] with x and w rounded to bf16, so
+    every product is exact in f32 (8-bit by 8-bit significands), and both
+    add the same K products in f32, in different orders: the oracle in
+    one dot, the kernel per value plane, sub-block and K block. Any order
+    of adding K terms errs by at most (K - 1) * u * sum_k |x_k w_k| with
+    u = 2**-24 (Higham, Accuracy and Stability, §4.2), so the two differ
+    by at most twice that. The weights enter as the oracle decodes them
+    (`ref.decompress`), so a decode that differed in a single weight
+    would exceed the bound only where its product does; the values are
+    checked exactly in tests/test_codecs.py. That bound is a worst case,
+    about 1000x the gaps these cases show (under 4e-5), so every case is
+    also held to the 1e-4 this check set the GEMV before."""
     from repro.kernels.deca_gemm import decompress_gemv_pallas
 
+    K, N, geometry = GEMV_GRIDS[grid]
     rng = np.random.default_rng(4)
-    K, N = 256, 96
     w = rng.standard_normal((K, N)).astype(np.float32)
-    ct = compress(w, get_spec("bf8_50"))
-    for m_rows in (1, 4, 8):
-        x = jnp.asarray(rng.standard_normal((m_rows, K)), jnp.float32)
-        want = np.asarray(ref.decompress_gemm(x, ct))
-        got = np.asarray(decompress_gemv_pallas(x, ct, interpret=True))
-        np.testing.assert_allclose(got, want, atol=1e-4)
+    ct = compress(w, get_spec(fmt))
+    x = jnp.asarray(rng.standard_normal((m_rows, K)), jnp.float32)
+    want = np.asarray(ref.decompress_gemm(x, ct))
+    got = np.asarray(
+        decompress_gemv_pallas(x, ct, interpret=True, **geometry)
+    )
+    assert got.shape == want.shape == (m_rows, N)
+    gap = np.abs(got - want)
+    assert (gap <= _f32_order_bound(x, ct)).all()
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-4)
+
+
+def _f32_order_bound(x, ct):
+    """2 (K - 1) u sum_k |x_k w_k|: how far two f32 sums of the same K
+    exact products can lie apart (see the test above)."""
+    xb = np.asarray(x.astype(jnp.bfloat16), np.float64)
+    wb = np.asarray(ref.decompress(ct, out_dtype=jnp.bfloat16), np.float64)
+    return 2 * (x.shape[1] - 1) * 2.0 ** -24 * (np.abs(xb) @ np.abs(wb))
+
+
+def test_gemv_route_follows_backend_and_m(monkeypatch):
+    """`ops.decompress_gemm` with no implementation asked for: on a TPU
+    (the backend test patched, the kernel interpreted) M <= GEMV_MAX_M
+    runs the fused kernel and M above it the GEMM route; explicit
+    `impl="ref"` runs the oracle anywhere. The trace-time counter counts
+    each GEMV call site under the implementation it lowered to."""
+    from repro.obs.metrics import TRACE_COUNTS
+
+    def sites():
+        return {
+            r: TRACE_COUNTS.counter(f"kernels.gemv_sites.{r}", "sites").value
+            for r in ("fused", "ref")
+        }
+
+    def pallas_calls(f, *args):
+        return str(jax.make_jaxpr(f)(*args)).count("pallas_call")
+
+    rng = np.random.default_rng(5)
+    K, N = 256, 128
+    ct = compress(rng.standard_normal((K, N)).astype(np.float32), get_spec("mxfp4_100"))
+    x = jnp.asarray(rng.standard_normal((4, K)), jnp.float32)
+    big = jnp.asarray(rng.standard_normal((ops.GEMV_MAX_M + 1, K)), jnp.float32)
+    oracle = np.asarray(ref.decompress_gemv(x, ct))
+
+    # on this backend, nothing changes: the oracle
+    before = sites()
+    assert pallas_calls(lambda x: ops.decompress_gemm(x, ct), x) == 0
+    assert sites() == {"fused": before["fused"], "ref": before["ref"] + 1}
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    before = sites()
+    assert pallas_calls(lambda x: ops.decompress_gemm(x, ct), x) == 1
+    assert sites() == {"fused": before["fused"] + 1, "ref": before["ref"]}
+    got = np.asarray(ops.decompress_gemm(x, ct))
+    assert (np.abs(got - oracle) <= _f32_order_bound(x, ct)).all()
+
+    before = sites()
+    assert pallas_calls(lambda x: ops.decompress_gemm(x, ct), big) == 0
+    assert sites() == before  # the GEMM route is no GEMV site
+
+    before = sites()
+    via_ref = ops.decompress_gemm(x, ct, impl="ref")
+    np.testing.assert_array_equal(np.asarray(via_ref), oracle)
+    assert pallas_calls(lambda x: ops.decompress_gemm(x, ct, impl="ref"), x) == 0
+    assert sites() == {"fused": before["fused"], "ref": before["ref"] + 2}
+
+
+def test_mesh_engine_traces_the_jnp_gemv(llama, monkeypatch):
+    """A mesh engine's weights are GSPMD-sharded, and GSPMD cannot
+    partition a Pallas call: with the backend test patched to a TPU, the
+    engine under a mesh traces every GEMV site on the jnp oracle, while
+    the same engine without a mesh takes the fused kernel."""
+    from repro.launch.mesh import make_test_mesh
+    from repro.obs.metrics import TRACE_COUNTS
+
+    def sites():
+        return {
+            r: TRACE_COUNTS.counter(f"kernels.gemv_sites.{r}", "sites").value
+            for r in ("fused", "ref")
+        }
+
+    m, params = llama
+    c = compress_tree(params, get_spec("mxfp4_100"))
+    prompts = _prompts(m.cfg.vocab_size, lengths=(5, 9))
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+
+    before = sites()
+    meshed, _ = _run(m, c, prompts, 3, chunk=2, mesh=make_test_mesh(1, 1))
+    after = sites()
+    assert after["fused"] == before["fused"]
+    assert after["ref"] > before["ref"]
+
+    plain, _ = _run(m, c, prompts, 3, chunk=2)
+    assert sites()["fused"] > after["fused"]
+    assert all(len(t) == 3 for t in meshed + plain)

@@ -112,6 +112,41 @@ def test_gemv_compiles_for_v5e(one_chip, fmt, which):
     )
 
 
+# every (format, K, N) decode GEMV of the benchmark's cells: OPT-66B at
+# mxfp4_100 and Granite-3.0-8B (hidden 4096, 8 KV heads of 128, FFN 12800)
+# at bf8_50
+CELL_GEMVS = (
+    ("mxfp4_100", D_MODEL, D_MODEL), ("mxfp4_100", D_MODEL, D_FF),
+    ("mxfp4_100", D_FF, D_MODEL), ("bf8_50", 4096, 4096),
+    ("bf8_50", 4096, 1024), ("bf8_50", 4096, 12800), ("bf8_50", 12800, 4096),
+)
+
+
+@pytest.mark.parametrize("fmt,k,n", CELL_GEMVS)
+def test_routed_gemv_compiles_for_v5e(one_chip, monkeypatch, fmt, k, n):
+    """`ops.decompress_gemm` as the serving path calls it (no impl asked
+    for), told it runs on a TPU: one fused kernel at the autotuned
+    geometry, with no dense (K, N) weight and no per-tile copy of the
+    code planes (the oracle's (N / block_n, ng, ., block_n) split)."""
+    import re
+
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    x = _struct((GEMV_M, k), jnp.bfloat16, one_chip)
+    ct = _ct_struct(fmt, k, n, one_chip)
+    hlo = (
+        jax.jit(lambda x, ct: ops.decompress_gemm(x, ct, out_dtype=x.dtype))
+        .lower(x, ct).compile().as_text()
+    )
+    assert hlo.count("tpu_custom_call") >= 1
+    assert not re.search(rf"(bf16|f32)\[{k},{n}\]", hlo)
+    ng = k // get_spec(fmt).group
+    shapes = re.findall(r"[a-z0-9]+\[(\d+),(\d+),(\d+),(\d+)\]", hlo)
+    assert not [s for s in shapes if int(s[1]) == ng], shapes
+
+
 @pytest.mark.parametrize("fmt", FORMATS)
 def test_gemm_compiles_for_v5e(one_chip, fmt):
     k, n = KN["sq"]
