@@ -63,7 +63,7 @@ def main(argv=None) -> int:
             eng.params = None
             gc.collect()
             eng.params, _ = weights.build_params(
-                eng.model, cell["cfg"]["weights"], seed)
+                eng.model, cell["cfg"]["weights"], seed, eng.mesh)
         window = run.Window(eng, cell, seed)
         window.run(args.seconds)
         drop_running(eng.scheduler)

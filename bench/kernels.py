@@ -189,9 +189,10 @@ def reduce(path, reduced) -> dict:
     intervals of the `XLA Ops` events whose op lies under the scope, so
     nested loops count once. `other_ns` is the program's time under no
     scope. Also the summed work arguments of the program's `serve.decode`
-    and `serve.prefill` spans in the same steps. Times are averaged over
-    the device planes. Empty where the program names nothing (a program
-    without scopes or spans)."""
+    and `serve.prefill` spans in the same steps. Intervals are merged
+    within each device plane, summed over the planes and averaged, so a
+    scope's time is per chip, as `devtrace.reduce`'s are. Empty where the
+    program names nothing (a program without scopes or spans)."""
     import mmap
 
     from jax.profiler import ProfileData
@@ -207,9 +208,10 @@ def reduce(path, reduced) -> dict:
     for st in reduced["steps"]:
         if st["covered"]:
             device.update(st["module_ns"])
+    # (program, scope or None for any scope, plane) -> op intervals
     scoped: dict = collections.defaultdict(list)
     resolved: dict = {}
-    for plane in planes:
+    for p, plane in enumerate(planes):
         lines = {line.name: line for line in plane.lines}
         if "XLA Modules" not in lines or "XLA Ops" not in lines:
             continue
@@ -228,17 +230,22 @@ def reduce(path, reduced) -> dict:
                 resolved[prog] = op_scopes(proto) if proto else {}
             op = ev.name.partition(" = ")[0].lstrip("%")
             for scope in resolved[prog].get(op, ()):
-                scoped[module_name(prog), scope].append((s, e))
+                scoped[module_name(prog), scope, p].append((s, e))
             if resolved[prog].get(op):
-                scoped[module_name(prog), None].append((s, e))
+                scoped[module_name(prog), None, p].append((s, e))
     n = max(1, len(planes))
+
+    def per_chip(prog, scope):
+        return sum(e - s for p in range(len(planes))
+                   for s, e in union(scoped.get((prog, scope, p), []))) / n
+
     modules: dict = {}
     for prog, ns in device.items():
-        times = {scope: sum(e - s for s, e in union(scoped[prog, scope])) / n
-                 for scope in SCOPES if scoped.get((prog, scope))}
-        any_scope = sum(e - s for s, e in union(scoped.get((prog, None), [])))
+        times = {scope: per_chip(prog, scope) for scope in SCOPES
+                 if any(scoped.get((prog, scope, p))
+                        for p in range(len(planes)))}
         modules[prog] = {"device_ns": ns, "scope_ns": times,
-                         "other_ns": max(0.0, ns - any_scope / n)}
+                         "other_ns": max(0.0, ns - per_chip(prog, None))}
     work: dict = {}
     for ev in _span_line(pd):
         if ev.name not in WORK or not _in(steps, starts, ev.start_ns):

@@ -23,3 +23,12 @@ def peaks_for(device_kind: str) -> dict:
             f"known: {sorted(PEAKS)}"
         )
     return PEAKS[device_kind]
+
+
+RATES = ("bf16_flops", "int8_ops", "hbm_bytes_per_s")
+
+
+def deployment(peak: dict, chips: int) -> dict:
+    """The peaks of `chips` chips serving together: a copy of one chip's
+    with each rate times `chips`; `hbm_bytes` stays one chip's."""
+    return dict(peak, **{k: peak[k] * chips for k in RATES})

@@ -8,6 +8,8 @@ One process runs one cell of BENCHMARK.json once:
 1. checks the device: a TPU whose `device_kind` is in `peaks.py`, with as
    many chips as the cell asks for; anything else exits 2 with no result;
 2. builds the served weights on the device from the seed (`weights.py`);
+   a cell of several chips serves on a (data=1, model=chips) mesh, each
+   chip building its own share of the weights in place;
 3. warms up every program shape the cell's traffic can reach, through the
    serving API itself, with JAX's persistent compilation cache kept at
    `<checkout>/.jax_cache`;
@@ -16,13 +18,15 @@ One process runs one cell of BENCHMARK.json once:
    host clock when `step()` returns with it visible; with `--trace 1` the
    last seconds of the window are traced by the profiler;
 5. frees the program's state and compares a seeded sample of the served
-   requests with the plain float32 reference (`reference.py`);
+   requests with the plain float32 reference (`reference.py`, or the
+   file under `bench/` that the configuration names as "reference");
 6. prints one JSON line: `correct`, `attempted`, `failed`, `metrics`,
    `device` (and `breakdown` when traced), then `checks`, the numbers
    compared with their limits, which also end standard error.
 
 Everything a cell needs is found by name: the configuration in
-`configs/<config>.json`, the traffic mix in `traffic/<traffic>.json`,
+`configs/<config>.json` (and its reference, the module it names),
+the traffic mix in `traffic/<traffic>.json`,
 each metric's reader in `metrics/<metric>.py` (a metric kept apart per cell,
 `<metric>.<cell kind>`, reads with `metrics/<metric>.py` where it has no file
 of its own).
@@ -172,6 +176,19 @@ def engine_settings(cell: dict) -> dict:
     }
 
 
+def cell_mesh(chips: int):
+    """The (data=1, model=chips) mesh over the first `chips` devices, the
+    layout `chip_smoke.py --chips 4` serves on; None for one chip."""
+    if chips == 1:
+        return None
+    import jax
+
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((1, chips), ("data", "model"),
+                     devices=jax.devices()[:chips])
+
+
 def build_engine(cell: dict, seed: int):
     import jax
 
@@ -183,8 +200,9 @@ def build_engine(cell: dict, seed: int):
 
     cfg = cell["cfg"]
     model = Model(ModelConfig(name=cfg["name"], **cfg["model"]))
+    mesh = cell_mesh(cell["chips"])
     t0 = time.perf_counter()
-    params, host_s = weights.build_params(model, cfg["weights"], seed)
+    params, host_s = weights.build_params(model, cfg["weights"], seed, mesh)
     jax.block_until_ready(params)
     built_s = time.perf_counter() - t0
     es = engine_settings(cell)
@@ -192,6 +210,7 @@ def build_engine(cell: dict, seed: int):
         model, params, max_len=es["max_len"], temperature=0.0,
         block_size=es["block_size"], max_slots=es["max_slots"],
         num_blocks=es["num_blocks"], decode_chunk=es["decode_chunk"],
+        mesh=mesh,
     )
     info(f"weights {cfg['weights']} built in {built_s:.2f} s "
          f"({host_s:.2f} s compressing slabs on the host); engine {es}")
@@ -348,9 +367,15 @@ class Window:
 # ---------------------------------------------------------------------------
 
 class Readings:
-    """Everything a metric reader may read, from one run."""
+    """Everything a metric reader may read, from one run. `peak` is the
+    deployment's: each rate of one chip's (`peaks.peaks_for`) times the
+    cell's chips, while the trace's times are the mean over the chips, so
+    a share reads the least time of the whole deployment over the time
+    each chip took, and work replicated over the chips counts as waste."""
 
     def __init__(self, cell, window, setup_s, stats_delta, trace, peak):
+        import peaks
+
         self.cell, self.model = cell, cell["cfg"]["model"]
         self.weights = cell["cfg"]["weights"]
         self.settings = engine_settings(cell)
@@ -358,7 +383,7 @@ class Readings:
         self.setup_s = setup_s
         self.stats = stats_delta
         self.trace = trace       # trace.reduce(...) or None
-        self.peak = peak         # peaks.peaks_for(device_kind)
+        self.peak = peaks.deployment(peak, cell["chips"])
         self.elapsed = window.t1 - window.t0
         self._traced = None
 
@@ -459,14 +484,32 @@ def gap_numbers(gaps) -> dict:
     return {"logit_gap": float(g.max()), "logit_gap_mean": float(g.mean())}
 
 
+_REFERENCES: dict = {}
+
+
+def reference_module(name: str):
+    """The plain reference `bench/<name>.py` that a configuration names as
+    its "reference" (default `reference`). Only a file beside this one can
+    be named, so no module of the program can stand in for it."""
+    path = BENCH / f"{name}.py"
+    if not (name.isidentifier() and path.is_file()):
+        raise ValueError(f"no reference module {name!r} under {BENCH}")
+    if name not in _REFERENCES:
+        spec = importlib.util.spec_from_file_location(f"reference_{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _REFERENCES[name] = mod
+    return _REFERENCES[name]
+
+
 def check(cell: dict, window: Window, seed: int, control: bool = False) -> dict:
     """The gaps of served tokens' reference logits below the reference's
     best, over a seeded sample of served requests, as `gap_numbers` (with
-    `control`, also the fp8 control's numbers on the same positions)."""
+    `control`, also the fp8 control's numbers on the same positions), from
+    the reference the configuration names (`reference_module`)."""
     import numpy as np
 
-    import reference
-
+    reference = reference_module(cell["cfg"].get("reference", "reference"))
     m, fmt = cell["cfg"]["model"], cell["cfg"]["weights"]
     pad_to = engine_settings(cell)["max_len"]
     sample = reference_sample(window, seed)
@@ -535,10 +578,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     reduced = None
     if trace:
         import devtrace
+        import kernels
         from jax.profiler import ProfileData
 
         files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
         reduced = devtrace.reduce(ProfileData.from_file(str(files[-1])))
+        reduced["kernels"] = kernels.reduce(files[-1], reduced)
         shutil.rmtree(trace_dir, ignore_errors=True)
     # free the program's state before the reference runs
     window.eng = window.sched = None

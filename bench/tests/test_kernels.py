@@ -118,11 +118,12 @@ def _host_plane(events, args):
             f"{meta}{smeta}}}\n")
 
 
-def kernel_trace(tmp_path):
+def kernel_trace(tmp_path, chips=1):
     """Two `bench.step`s: the first runs a prefill and a decode chunk whose
     ops nest (a tile loop inside the layer scan); the second runs a chunk
     inside a stretch whose device buffers were dropped, so neither its time
-    nor its span arguments count."""
+    nor its span arguments count. Each of `chips` device planes runs the
+    same ops at the same times, as chips of one mesh do."""
     decode = {"scan_steps": 8, "slots": 2, "kv_tokens": 100}
     host = _host_plane([
         ("bench.traced", 0, 2000),
@@ -138,7 +139,7 @@ def kernel_trace(tmp_path):
     meta = _stat_plane(3, "/host:metadata", {
         "jit_chunk_step(7)": CHUNK_HLO, "jit_paged_prefill(9)": PREFILL_HLO,
     })
-    dev = _plane(2, "/device:TPU:0", {
+    dev = "".join(_plane(4 + i, f"/device:TPU:{i}", {
         "XLA Modules": [(1, 120, 70), (2, 330, 380), (2, 1100, 400)],
         "XLA Ops": [
             (10, 120, 50), (11, 170, 20),                     # prefill
@@ -155,6 +156,7 @@ def kernel_trace(tmp_path):
         14: "%fusion.3 = f32[4,128] fusion(c)", 15: "%copy.4 = f32[4] copy(d)",
         16: "%fusion.5 = f32[4] fusion(e)", 17: "%fusion.6 = f32[4] fusion(f)",
         18: "%fusion.7 = f32[4] fusion(g)", 19: "Trace Buffers Dropped"})
+        for i in range(chips))
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(ProfileData.text_proto_to_serialized_xspace(host + meta + dev))
     return path
@@ -175,6 +177,27 @@ def test_scope_times_count_nesting_once(tmp_path):
     assert k["modules"]["jit_paged_prefill"] == {
         "device_ns": 70, "scope_ns": {"deca.gemm": 50, "model.attention": 20},
         "other_ns": 0}
+
+
+def test_scope_times_are_per_chip(tmp_path):
+    """Two chips running the same ops at once: every time reads as one
+    chip's, so a kernel's share of the two chips' peaks is half its share
+    of one chip's."""
+    got = {}
+    for chips in (1, 2):
+        path = kernel_trace(tmp_path, chips)
+        reduced = devtrace.reduce(ProfileData.from_file(str(path)))
+        assert reduced["devices"] == chips
+        got[chips] = kernels.reduce(path, reduced)
+    assert got[2] == got[1]
+    one = _readings({"module_ns": {}, "kernels": got[1]})
+    two = _readings({"module_ns": {}, "kernels": got[2]})
+    two.peak = peaks.deployment(one.peak, 2)
+    for name in ("gemv_roofline", "attn_roofline"):
+        share = _reader(name)(one)["value"]
+        assert share > 0
+        assert _reader(name)(two)["value"] == pytest.approx(share / 2,
+                                                            rel=1e-12)
 
 
 def test_sum_the_work_of_covered_steps_only(tmp_path):
@@ -279,3 +302,42 @@ def test_kernel_readers_divide_stated_work_by_scope_time(name, expect):
 ])
 def test_kernel_readers_read_nothing_without_scopes(name, trace):
     assert _reader(name)(_readings(trace)) is None
+
+
+def test_shares_read_against_the_deployments_peaks():
+    """The same readings on 1 and on 4 chips: every share of a roofline or
+    a peak reads 4 times lower on 4 (the trace's times are per chip, the
+    work the whole model's), every other metric alike."""
+    import json
+
+    import numpy as np
+
+    import run
+    from conftest import smoke_cell
+
+    window = types.SimpleNamespace(
+        t0=0.0, t1=2.0,
+        records=[{"rid": 1, "prompt": np.zeros(30, np.int32), "count": 13}],
+        steps=[(0.0, 1.0, True, [(1, 0, 5)]), (1.0, 2.0, True, [(1, 5, 13)])])
+    trace = {"window_ns": 2e9, "busy_ns": 1.5e9, "kernels": KERNELS,
+             "module_ns": {"jit_chunk_step": 3e9, "jit_paged_prefill": 1e9},
+             "steps": [{"covered": True}, {"covered": True}]}
+    stats = {"decode_steps": 12, "active_slot_steps": 40,
+             "prefill_token_steps": 64, "prefill_real_tokens": 30}
+    names = [m["name"] for m in json.loads(
+        (BENCH.parent / "BENCHMARK.json").read_text())["per_layer"]]
+    read = {}
+    for chips in (1, 4):
+        cell = dict(smoke_cell("opt"), chips=chips)
+        r = run.Readings(cell, window, 1.0, stats, trace,
+                         peaks.PEAKS["TPU v5 lite"])
+        got = {n: run.reader(n)(r) for n in names}
+        read[chips] = {n: v["value"] if isinstance(v, dict) else v
+                       for n, v in got.items()}
+    # a metric this trace does not feed reads nothing at either count
+    names = [n for n in names if read[1][n] is not None]
+    assert names and all(read[4][n] is not None for n in names)
+    shares = [n for n in names if "roofline" in n or "mfu" in n]
+    assert shares and all(read[1][n] > 0 for n in names)
+    for n in names:
+        assert read[1][n] == (4 * read[4][n] if n in shares else read[4][n]), n

@@ -1,11 +1,12 @@
 """The whole run on the CPU at smoke sizes, with the TPU check stubbed:
 warm-up, window, metrics, reference check, and the result line."""
 import json
+import os
 
 import pytest
 
 import run
-from conftest import smoke_cell
+from conftest import BENCH, smoke_cell
 
 
 @pytest.mark.parametrize("arch", ["opt", "granite"])
@@ -46,3 +47,34 @@ def test_no_tpu_no_result(capsys, monkeypatch):
                    "--seconds", "1", "--trace", "0"])
     assert rc != 0
     assert capsys.readouterr().out.strip() == ""
+
+
+def test_a_configuration_names_its_own_reference(cpu_device):
+    """A configuration's "reference" names the file under `bench/` that
+    `run.check` loads: here a copy of `reference.py` that records each call
+    it serves."""
+    identity, peak = cpu_device
+    name = f"reference_copy_{os.getpid()}"
+    path = BENCH / f"{name}.py"
+    path.write_text(
+        (BENCH / "reference.py").read_text() + "\n\nSEEDS = []\n"
+        "_served_gaps = served_gaps\n\n\n"
+        "def served_gaps(m, weight_format, seed, *args, **kwargs):\n"
+        "    SEEDS.append(seed)\n"
+        "    return _served_gaps(m, weight_format, seed, *args, **kwargs)\n")
+    cell = smoke_cell("opt")
+    cell["cfg"]["reference"] = name
+    try:
+        line = run.run_cell(cell, 31, 2.0, False, identity, peak)
+        assert run.reference_module(name).SEEDS == [31]
+    finally:
+        path.unlink()
+        run._REFERENCES.pop(name, None)
+    assert line["correct"], line["checks"]
+
+
+@pytest.mark.parametrize("name", ["repro", "../src/repro/serve/engine",
+                                  "no_such_reference"])
+def test_a_reference_outside_the_benchmark_is_refused(name):
+    with pytest.raises(ValueError, match="no reference module"):
+        run.reference_module(name)

@@ -107,10 +107,11 @@ def _std(role: str, shape) -> float:
     raise ValueError(f"no rule for a weight of role {role!r}")
 
 
-def dense_leaves(leaves, seed: int) -> dict:
+def dense_leaves(leaves, seed: int, shardings=None) -> dict:
     """Every weight that is not an FC weight, made on the device in one call
     from the seed: `leaves` is [(role, shape, dtype)], the result
-    {role: array}. Embeddings N(0, 0.02^2), norm gains N(0, 0.1^2)."""
+    {role: array}, placed by `shardings` ({role: sharding}) where given.
+    Embeddings N(0, 0.02^2), norm gains N(0, 0.1^2)."""
     leaves = [(r, tuple(s), jnp.dtype(d)) for r, s, d in leaves]
 
     def make(key):
@@ -122,13 +123,44 @@ def dense_leaves(leaves, seed: int) -> dict:
             for role, shape, dtype in leaves
         }
 
-    return jax.jit(make)(jax.random.PRNGKey(seed_words(seed, 2)))
+    return jax.jit(make, out_shardings=shardings)(
+        jax.random.PRNGKey(seed_words(seed, 2)))
 
 
-def build_params(model, spec_name: str, seed: int):
+def placements(abstract, mesh, scan_stacked: bool):
+    """The program's own serving placement of a parameter tree (arrays or
+    shapes, compressed weights as `CompressedTensor`s) on `mesh`, as
+    ({role: {plane: sharding}} of the FC weights, {role: sharding} of the
+    rest): `dist.sharding.param_spec_tree`, the rule the engine's
+    `shard_params` applies, so it finds every leaf already in place."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core.compression import CompressedTensor
+    from repro.dist.sharding import ShardingCtx, param_spec_tree
+
+    specs = param_spec_tree(abstract, ShardingCtx(mesh, mode="serve"),
+                            scan_stacked=scan_stacked)
+    fc, dense = {}, {}
+    for path, spec in jax.tree_util.tree_flatten_with_path(
+            specs, is_leaf=lambda x: isinstance(x, (CompressedTensor, PartitionSpec))
+    )[0]:
+        role = role_of(path)
+        if isinstance(spec, CompressedTensor):
+            fc[role] = {name: NamedSharding(mesh, getattr(spec, name))
+                        for name in ("codes", "mask", "scales")
+                        if getattr(spec, name) is not None}
+        else:
+            dense[role] = NamedSharding(mesh, spec)
+    return fc, dense
+
+
+def build_params(model, spec_name: str, seed: int, mesh=None):
     """The served parameter tree: FC weights as stacked `CompressedTensor`s
-    built on the device, the rest from `dense_leaves`. Returns (params,
-    seconds spent compressing slabs on the host)."""
+    built on the device, the rest from `dense_leaves`. With a `mesh`, each
+    build is compiled with the program's placement (`placements`) as its
+    output sharding, so no chip ever holds more than its share; the values
+    are those of the build without one. Returns (params, seconds spent
+    compressing slabs on the host)."""
     import time
 
     from repro.core.compression import CompressedTensor, compress
@@ -160,28 +192,34 @@ def build_params(model, spec_name: str, seed: int):
             }
         return out
 
-    # the keys are arguments, not constants: one program serves every seed
-    built = jax.jit(build)(slabs, {role: fc_key(seed, role) for role, _ in layout})
     shapes = dict(layout)
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
-    dense = dense_leaves(
-        [(role_of(p), leaf.shape, leaf.dtype) for p, leaf in flat
-         if role_of(p) not in shapes],
-        seed,
-    )
+    others = [(role_of(p), leaf.shape, leaf.dtype) for p, leaf in flat
+              if role_of(p) not in shapes]
 
-    def leaf(path):
-        role = role_of(path)
-        if role not in shapes:
-            return dense[role]
-        planes = built[role]
-        return CompressedTensor(
-            codes=planes["codes"], mask=planes.get("mask"),
-            scales=planes.get("scales"), spec=spec, shape=shapes[role][1:],
-        )
+    def assemble(built, dense):
+        def leaf(path):
+            role = role_of(path)
+            if role not in shapes:
+                return dense[role]
+            planes = built[role]
+            return CompressedTensor(
+                codes=planes["codes"], mask=planes.get("mask"),
+                scales=planes.get("scales"), spec=spec, shape=shapes[role][1:],
+            )
 
-    params = jax.tree_util.tree_unflatten(treedef, [leaf(p) for p, _ in flat])
-    return params, host_s
+        return jax.tree_util.tree_unflatten(treedef, [leaf(p) for p, _ in flat])
+
+    # the keys are arguments, not constants: one program serves every seed
+    keys = {role: fc_key(seed, role) for role, _ in layout}
+    fc_out = dense_out = None
+    if mesh is not None:
+        fc_out, dense_out = placements(
+            assemble(jax.eval_shape(build, slabs, keys),
+                     {r: jax.ShapeDtypeStruct(s, d) for r, s, d in others}),
+            mesh, model.uniform)
+    built = jax.jit(build, out_shardings=fc_out)(slabs, keys)
+    return assemble(built, dense_leaves(others, seed, dense_out)), host_s
 
 
 def dense_fc(layout, spec_name: str, seed: int):
